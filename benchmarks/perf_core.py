@@ -352,9 +352,11 @@ def bench_device_grid(names, N: int, alphas, ms, css) -> dict:
     the jax backend (``backend.stats``) and that every grid point of
     both ``sweep_grid`` and ``suite_sweep_grid`` is bit-identical to the
     float64 numpy reference — f32 is an execution strategy, never an
-    answer.  On CPU hosts the pallas step runs in interpret mode, so the
-    timings here measure the dispatch pipeline, not accelerator FLOPs;
-    the assertions are the gate."""
+    answer.  On a TPU every chunk must run on the device: a device pass
+    that fails raises rather than run on numpy.  On CPU hosts the pallas
+    step runs in interpret mode, so the timings there measure the
+    interpreter and the dispatch pipeline, not the device; the assertions
+    are the gate."""
     from repro.core import backend as bk
 
     try:
@@ -418,6 +420,9 @@ def _device_grid_body(bk, names, N: int, alphas, ms, css) -> dict:
     frac = stats["jax_chunks"] / max(stats["chunks"], 1)
     assert frac >= 0.9, \
         f"only {frac:.0%} of replay chunks ran on the jax backend"
+    if bk.on_tpu():
+        assert stats["numpy_chunks"] == 0 and stats["demoted_columns"] == 0, \
+            f"replay chunks left the TPU: {stats}"
     return dict(name=f"device_grid_{len(names)}x_N{N}",
                 n_traces=len(names),
                 n_points=int(sum(r.size for r in ref)),
@@ -490,6 +495,10 @@ def bench_schedule_cache(name: str, N: int, alphas, ms, css,
     cold_runs, warm_runs = [], []
     with tempfile.TemporaryDirectory() as td:
         base = dict(os.environ,
+                    # the children measure host-side recording: keep them
+                    # off the accelerator, which belongs to one process
+                    # (this one may already hold it)
+                    JAX_PLATFORMS="cpu", EDAN_BACKEND="numpy",
                     # self-contained: don't inherit caller floors/caps
                     EDAN_SCHEDULE_CACHE_MIN="0",
                     EDAN_SCHEDULE_CACHE_MAX=str(10 ** 6),

@@ -29,7 +29,8 @@ For the *replay* matrices (float64) the jax path additionally supports two
 device-resident execution strategies behind ``replay_accumulate``:
 
 * **x64 mode** (``EDAN_X64=1`` / ``replay_dtype="float64"``) enables
-  jax's x64 flag and runs the exact float64 recurrence on device.
+  jax's x64 flag and runs the exact float64 recurrence on device.  Not on
+  a TPU, which has no float64 kernels: asking for it there raises.
 * **error-bounded float32 mode** (the default on non-x64 jax) runs the
   stacked pass in float32 on device, then certifies each column against
   a per-level error bound on host: finish times are nonnegative integer
@@ -39,6 +40,14 @@ device-resident execution strategies behind ``replay_accumulate``:
   fail the bound are demoted to the numpy float64 kernel, so returned
   results are unconditionally bit-exact; float32 is an execution
   strategy, never an answer.
+
+A device pass that fails (compile, run, device memory) raises
+``DeviceReplayError`` naming the plan shape.  The backend never demotes a
+failed pass to numpy on its own; the analysis service's ladder
+(``plan.ExecPolicy.ladder``) is the one place that does, and says so in
+its result.  The numpy routings that remain on the jax backend are
+decided before the device call (float64 input the device cannot run) or
+after it (uncertified float32 columns), and each is counted in ``stats``.
 """
 from __future__ import annotations
 
@@ -62,20 +71,22 @@ _REPLAY_DTYPES = ("float32", "float64")
 #: those the numpy kernel handled end to end (including chunks whose f32
 #: pass certified no column at all); ``certified_columns`` /
 #: ``demoted_columns`` count sweep columns the float32 certificate
-#: accepted / demoted to the float64 numpy kernel.  Thread-safe: the
+#: accepted / demoted to the float64 numpy kernel.  ``numpy_f64_passes``
+#: counts plain ``level_accumulate`` passes the jax backend routed to the
+#: numpy kernel because the device cannot run their float64 input
+#: exactly (no x64 flag, or a TPU).  Thread-safe: the
 #: analysis service replays concurrent batches, and lost increments here
 #: would skew the very counters its benchmarks and fault-injection gates
 #: assert on.
 stats = Stats(chunks=0, jax_chunks=0, jax_f64_chunks=0, numpy_chunks=0,
-              certified_columns=0, demoted_columns=0)
+              certified_columns=0, demoted_columns=0, numpy_f64_passes=0)
 
 #: Fault-injection hook (``serve.faults``): when set, called with no
 #: arguments at the top of the jax kernel path.  An exception it raises
-#: is swallowed by the kernel dispatch's existing best-effort fallback,
-#: demoting the pass to the numpy float64 kernel — the hook exists so
-#: the fault-injection suite can *prove* that in-kernel backend failures
-#: degrade through the ladder without changing a bit of any result.
-#: Never set outside tests/fault injection.
+#: propagates like a real device failure — the backend never demotes on
+#: its own — so the fault-injection suite can *prove* that in-kernel
+#: backend failures degrade through the service's ladder without
+#: changing a bit of any result.  Never set outside tests/fault injection.
 fault_hook = None
 
 
@@ -105,14 +116,47 @@ def select_backend(override: Optional[str] = None) -> str:
                              f"{_BACKENDS}")
         return choice
     if _AUTO_BACKEND is None:
-        _AUTO_BACKEND = "numpy"
-        try:
-            import jax
-            if any(d.platform != "cpu" for d in jax.devices()):
-                _AUTO_BACKEND = "jax"
-        except Exception:
-            pass
+        import jax
+        _AUTO_BACKEND = ("jax" if any(d.platform != "cpu"
+                                      for d in jax.devices()) else "numpy")
     return _AUTO_BACKEND
+
+
+def on_tpu() -> bool:
+    """Whether jax's default backend is a TPU."""
+    import jax
+    return jax.default_backend() == "tpu"
+
+
+def _device_has_f64() -> bool:
+    """Whether the device pass can run float64: jax must have the x64
+    flag on, and the default backend must not be a TPU (Pallas on TPU
+    has no float64; XLA refuses to rewrite the kernel call)."""
+    import jax
+    return bool(jax.config.jax_enable_x64) and not on_tpu()
+
+
+#: The checkout root (``src/repro/core`` sits three levels below it).
+_CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def configure_compile_cache() -> str:
+    """Point jax's persistent compilation cache at a fixed directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set, then a directory
+    already set in jax's config.  Otherwise the cache goes to
+    ``.jax_cache/`` at the checkout root, a path derived only from where
+    the package sits, so a later process in the same checkout finds the
+    same entries.  Call it before the first jit; returns the directory in
+    use."""
+    import jax
+    path = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or jax.config.jax_compilation_cache_dir
+            or os.path.join(_CHECKOUT_ROOT, ".jax_cache"))
+    if jax.config.jax_compilation_cache_dir != path:
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 _TRUTHY = ("1", "true", "yes", "on")
@@ -385,6 +429,39 @@ def _accumulate_numpy(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
 _JAX_CACHE: OrderedDict = OrderedDict()
 _JAX_CACHE_CAP = 8
 
+#: Largest row block of one Pallas grid step, and the VMEM one step's
+#: tiles may take.  A TPU core's scoped VMEM is 16 MiB by default; a
+#: level wider than one block (analytic sweeps over wide traces reach
+#: tens of thousands of rows) would not fit in it whole.
+_ROW_TILE = 512
+_STEP_VMEM_BYTES = 8 * 1024 * 1024
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _row_block(R: int, D: int, k: int) -> int:
+    """Rows per grid step of the Pallas level step for an (R, D, k) level.
+
+    VMEM lays a 32-bit tile out in (8, 128) blocks of its last two dims,
+    so one row costs the padded (D, k) slab of ``seg`` — double-buffered,
+    plus the kernel's two temporaries of that size — the padded index row
+    and the (k,) rows of ``fq``, ``base``, ``new`` and ``ready``, each
+    double-buffered.  The block is the whole level when that fits the
+    budget, else the largest multiple of 8 (at most ``_ROW_TILE``) that
+    divides R; ``_jax_padded`` pads R so that one exists."""
+    lanes = _round_up(k, 128)
+    row = 4 * (4 * _round_up(D, 8) * lanes + 2 * _round_up(D, 128)
+               + 8 * lanes)
+    cap = max(_STEP_VMEM_BYTES // row, 8)
+    if R <= cap:
+        return R
+    tr = min(cap, _ROW_TILE) // 8 * 8
+    while R % tr:
+        tr -= 8
+    return tr
+
 
 def _jax_padded(lv: LevelCSR):
     """Pad the per-level runs to rectangles for the jitted level loop.
@@ -392,7 +469,13 @@ def _jax_padded(lv: LevelCSR):
     Queue-only vertices (no DAG predecessor, just a slot chain) become
     zero-width runs — their reduce sees only the folded-in qpred entry.
     The padded tensors depend only on the partition, so they are memoized
-    on the LevelCSR (chunked sweeps call the kernel several times)."""
+    on the LevelCSR (chunked sweeps call the kernel several times).
+
+    The row axis is padded to a multiple of 128, or of ``_ROW_TILE`` past
+    it, so the Pallas step's row blocks tile it exactly.  On a TPU a row
+    axis off the 128-lane width also makes XLA relayout the whole gather
+    tensor on every call (8 GB of device temp for the PAPER_15 m=8
+    union plan)."""
     if lv.jax_padded is not None:
         return lv.jax_padded
     L = lv.n_levels
@@ -401,6 +484,8 @@ def _jax_padded(lv: LevelCSR):
                else np.zeros(max(L, 1), dtype=np.int64))
     Rmax = int((rcounts + qcounts[:len(rcounts)]).max()) if len(rcounts) \
         else 0
+    Rmax = (_round_up(max(Rmax, 1), 128) if Rmax <= _ROW_TILE
+            else _round_up(Rmax, _ROW_TILE))
     Dmax = int(lv.run_lens.max()) if len(lv.run_lens) else 1
     gather = np.full((L, Rmax, Dmax), -1, dtype=np.int32)
     dsts = np.full((L, Rmax), -1, dtype=np.int32)
@@ -418,30 +503,45 @@ def _jax_padded(lv: LevelCSR):
     return lv.jax_padded
 
 
-def _pallas_level_step(seg, mask, fq, base, clamp: bool, has_q: bool,
+def _pallas_interpret() -> bool:
+    """Run the Pallas level step in interpret mode: on CPU hosts only.
+
+    On an accelerator the step is compiled (Mosaic on TPU); interpret
+    mode exists for the CPU test hosts.  A compile rehearsal for a
+    described chip steers this function from the test itself."""
+    import jax
+    return jax.default_backend() == "cpu"
+
+
+def _pallas_level_step(seg, idx, fq, base, clamp: bool, has_q: bool,
                        want_r: bool):
     """Segmented-max/slot-update inner step as a pallas kernel.
 
-    ``seg``  (R, D, k) gathered DAG-predecessor finish rows (masked where
-    invalid), ``mask`` (R, D) validity, ``fq`` (R, k) the queue
-    predecessor's finish rows (the slot chain; the zero sentinel row when
-    absent — only consulted when ``has_q``), ``base`` (R, k) the dst base
-    costs.  Returns the pair ``(new, ready)``: the new (R, k) finish rows
-    and, when ``want_r``, the DAG-predecessor-only maxima (the
-    simulator's ready times, 0 where a destination has no DAG
-    predecessor; ``None`` otherwise, sparing the analytic sweeps the
-    extra per-level output store).  Both halves of the recurrence come
-    out of one kernel launch, so the verification pass of the batched
-    simulator needs no numpy round-trip.  Interpreted on CPU; compiled
-    on TPU/GPU.
-    """
+    ``seg``  (R, D, k) gathered DAG-predecessor finish rows, ``idx``
+    (R, D) int32 the gather index they came from (-1 marks padding, so
+    validity is ``idx >= 0``), ``fq`` (R, k) the queue predecessor's
+    finish rows (the slot chain; the zero sentinel row when absent —
+    only consulted when ``has_q``), ``base`` (R, k) the dst base costs.
+    Returns the pair ``(new, ready)``: the new (R, k) finish rows and,
+    when ``want_r``, the DAG-predecessor-only maxima (the simulator's
+    ready times, 0 where a destination has no DAG predecessor; ``None``
+    otherwise, sparing the analytic sweeps the extra per-level output
+    store).  Both halves of the recurrence come out of one kernel launch,
+    so the verification pass of the batched simulator needs no numpy
+    round-trip.
+
+    Validity enters as the int32 index and is compared only after the
+    reshape to (R, D, 1): Mosaic cannot reshape a boolean vector, so a
+    bool mask input does not compile for the TPU.  Rows are independent,
+    so the step runs as a grid over row blocks (``_row_block``) that each
+    fit VMEM."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    def kernel(seg_ref, mask_ref, fq_ref, base_ref, out_ref, r_ref=None):
+    def kernel(seg_ref, idx_ref, fq_ref, base_ref, out_ref, r_ref=None):
         s = seg_ref[:]                          # (R, D, k)
-        valid = mask_ref[:][:, :, None]
+        valid = idx_ref[:][:, :, None] >= 0     # (R, D, 1)
         neg = jnp.full_like(s, -jnp.inf)
         red = jnp.max(jnp.where(valid, s, neg), axis=1)
         has = jnp.any(valid, axis=1)            # (R, 1)
@@ -459,14 +559,65 @@ def _pallas_level_step(seg, mask, fq, base, clamp: bool, has_q: bool,
             red = jnp.maximum(red, 0.0)
         out_ref[:] = red + base_ref[:]
 
-    interpret = jax.default_backend() == "cpu"
+    R, D, k = seg.shape
+    tr = _row_block(R, D, k)
+    rows = pl.BlockSpec((tr, k), lambda i: (i, 0))
     shape = jax.ShapeDtypeStruct(base.shape, base.dtype)
     res = pl.pallas_call(
         kernel,
         out_shape=(shape, shape) if want_r else shape,
-        interpret=interpret,
-    )(seg, mask, fq, base)
+        grid=(R // tr,),
+        in_specs=[pl.BlockSpec((tr, D, k), lambda i: (i, 0, 0)),
+                  pl.BlockSpec((tr, D), lambda i: (i, 0)), rows, rows],
+        out_specs=(rows, rows) if want_r else rows,
+        interpret=_pallas_interpret(),
+        name="edan_level_step",
+    )(seg, idx, fq, base)
     return res if want_r else (res, None)
+
+
+def _level_loop(has_q: bool, clamp: bool, want_r: bool):
+    """The device level loop for one flag set, un-jitted.
+
+    ``run(F, R, gather, dsts, qpred)`` walks levels 1..L-1 with a
+    ``fori_loop``: gather the predecessor rows, call the Pallas step,
+    scatter the new rows back.  The graph arrays are arguments, so one
+    jitted ``run`` re-specializes per plan shape on its own."""
+    import jax
+    import jax.numpy as jnp
+
+    def run(Fin, Rin, gat, dst_pad, qpred):
+        L = gat.shape[0]
+
+        def body(lvl, carry):
+            Fcur, Rcur = carry
+            g = gat[lvl]                        # (R, D)
+            d = dst_pad[lvl]                    # (R,)
+            seg = Fcur[jnp.maximum(g, 0)]       # (R, D, k)
+            dc = jnp.maximum(d, 0)
+            # the queue predecessor's finish (slot chain); missing
+            # predecessors hit the zero sentinel row, i.e. a slot that
+            # is free at t=0
+            fq = Fcur[qpred[dc]] if has_q else Fcur[dc]
+            new, r = _pallas_level_step(seg, g, fq, Fcur[dc], clamp,
+                                        has_q, want_r)
+            keep = (d >= 0)[:, None]
+            Fnext = Fcur.at[dc].set(jnp.where(keep, new, Fcur[dc]))
+            if want_r:
+                Rcur = Rcur.at[dc].set(jnp.where(keep, r, Rcur[dc]))
+            return Fnext, Rcur
+
+        return jax.lax.fori_loop(1, L, body, (Fin, Rin))
+
+    return run
+
+
+class DeviceReplayError(RuntimeError):
+    """A level pass failed on the jax backend (compile, run or device
+    memory).  The message names the plan shape; the original error is
+    chained as ``__cause__``.  The backend never turns such a failure
+    into a numpy result: the caller decides (the analysis service's
+    demotion ladder is the one place that demotes)."""
 
 
 def _accumulate_jax(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
@@ -474,27 +625,25 @@ def _accumulate_jax(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
     """jax backend: jit-compiled level loop + pallas inner step.
 
     Computes the same (max,+) recurrence as the numpy kernel in the input
-    dtype.  Queue predecessors (slot chains) are folded inside the pallas
+    dtype, which the caller has already checked the device can run
+    (``level_accumulate`` / ``replay_accumulate`` route float64 without
+    the x64 flag, and float64 on a TPU, to the numpy kernel before this
+    call).  Queue predecessors (slot chains) are folded inside the pallas
     step, which also emits the DAG-predecessor-only maxima per level — so
     when ``R_out`` is requested (the batched simulator's ready-time /
     order-verification pass) the whole recurrence, finish times *and*
     ready times, runs on the accelerator in one fused level loop with no
-    numpy round-trip.
+    numpy round-trip.  Any device failure raises ``DeviceReplayError``.
     """
     import jax
     import jax.numpy as jnp
 
     if fault_hook is not None:
-        # fault injection (serve.faults): a raising hook is caught by the
-        # callers' best-effort dispatch and demotes this pass to numpy
+        # fault injection (serve.faults): the raised error propagates
+        # like a device failure, up to the service's demotion ladder
         fault_hook()
 
-    if F.dtype == np.float64 and not jax.config.jax_enable_x64:
-        # without the x64 flag jax would silently truncate to float32 and
-        # hand back drifted values in a float64 array; exactness beats
-        # device execution, so keep such inputs on the numpy kernel
-        return _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
-
+    configure_compile_cache()
     gather, dsts = _jax_padded(lv)
     has_q = lv.qpred is not None
     want_r = R_out is not None
@@ -506,45 +655,28 @@ def _accumulate_jax(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
     # sweeps and x64-mode replays each get their own bounded slot
     key = (has_q, clamp, want_r, F.dtype.str,
            bool(jax.config.jax_enable_x64))
-
-    def run(Fin, Rin, gat, dst_pad, qpred):
-        L = gat.shape[0]
-
-        def body(lvl, carry):
-            Fcur, Rcur = carry
-            g = gat[lvl]                        # (R, D)
-            d = dst_pad[lvl]                    # (R,)
-            seg = Fcur[jnp.maximum(g, 0)]       # (R, D, k)
-            mask = g >= 0
-            dc = jnp.maximum(d, 0)
-            # the queue predecessor's finish (slot chain); missing
-            # predecessors hit the zero sentinel row, i.e. a slot that
-            # is free at t=0
-            fq = Fcur[qpred[dc]] if has_q else Fcur[dc]
-            new, r = _pallas_level_step(seg, mask, fq, Fcur[dc], clamp,
-                                        has_q, want_r)
-            keep = (d >= 0)[:, None]
-            Fnext = Fcur.at[dc].set(jnp.where(keep, new, Fcur[dc]))
-            if want_r:
-                Rcur = Rcur.at[dc].set(jnp.where(keep, r, Rcur[dc]))
-            return Fnext, Rcur
-
-        return jax.lax.fori_loop(1, L, body, (Fin, Rin))
-
     fn = _JAX_CACHE.get(key)
     if fn is None:
-        fn = jax.jit(run)
+        fn = jax.jit(_level_loop(has_q, clamp, want_r))
         _JAX_CACHE[key] = fn
     _JAX_CACHE.move_to_end(key)
     while len(_JAX_CACHE) > _JAX_CACHE_CAP:
         _JAX_CACHE.popitem(last=False)
-    Rin = jnp.asarray(R_out) if want_r else jnp.zeros((1, F.shape[1]),
-                                                      dtype=F.dtype)
-    Fj, Rj = fn(jnp.asarray(F), Rin, jnp.asarray(gather), jnp.asarray(dsts),
-                jnp.asarray(qp))
-    F[:] = np.asarray(Fj)
-    if want_r:
-        R_out[:] = np.asarray(Rj)
+    try:
+        Rin = jnp.asarray(R_out) if want_r else jnp.zeros(
+            (1, F.shape[1]), dtype=F.dtype)
+        Fj, Rj = fn(jnp.asarray(F), Rin, jnp.asarray(gather),
+                    jnp.asarray(dsts), jnp.asarray(qp))
+        F[:] = np.asarray(Fj)
+        if want_r:
+            R_out[:] = np.asarray(Rj)
+    except Exception as exc:
+        raise DeviceReplayError(
+            f"device level pass failed on {jax.default_backend()} for plan "
+            f"(L, Rmax, Dmax)={gather.shape}, rows={F.shape[0]}, "
+            f"k={F.shape[1]}, dtype={F.dtype}, has_q={has_q}, "
+            f"clamp={clamp}, want_r={want_r}: {type(exc).__name__}: "
+            f"{exc}") from exc
     return F
 
 
@@ -585,15 +717,19 @@ def level_accumulate(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
 
     Returns ``F`` (mutated in place).  For a fixed dtype the backends
     agree bit-for-bit: max is exact and every ``+ base`` is one IEEE add.
+
+    On the jax backend a float64 ``F`` runs on the device only where the
+    device computes float64 (x64 flag on, not a TPU); otherwise it is
+    routed to the numpy kernel before any device call and counted in
+    ``stats["numpy_f64_passes"]``.  A failing device pass raises
+    ``DeviceReplayError``; it is never turned into a numpy result.
     """
-    b = select_backend(backend)
-    if b == "jax":
-        try:
+    if select_backend(backend) == "jax":
+        if F.dtype != np.float64 or _device_has_f64():
             return _accumulate_jax(lv, F, clamp=clamp, R_out=R_out)
-        except Exception:
-            # accelerator path is best-effort: never fail an analysis over
-            # a backend issue, fall back to the reference numpy kernel
-            return _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
+        # float64 input the device cannot run exactly (no x64 flag: jax
+        # would truncate to float32; a TPU: no float64 kernels)
+        stats.add("numpy_f64_passes")
     return _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
 
 
@@ -700,14 +836,19 @@ def replay_accumulate(lv: LevelCSR, F: np.ndarray, quanta: np.ndarray,
     * numpy backend selected: the float64 numpy kernel, unchanged.
     * jax + ``float64`` policy (``EDAN_X64=1`` / ``replay_dtype=
       "float64"``), or jax already running with the x64 flag: enable
-      x64 and run the exact float64 pass on device.
+      x64 and run the exact float64 pass on device.  A TPU has no
+      float64 kernels: there the explicit policy raises ``ValueError``,
+      and a process already running with the x64 flag takes the float32
+      mode below.
     * jax + ``float32`` policy (the default): run the pass in float32 on
       device, certify each column against the ``column_quanta`` /
       per-level error bound (``_certified_f32``), and demote only the
       failing columns to the float64 numpy kernel.
 
     ``quanta`` is the per-column quantum from ``column_quanta`` (length
-    k).  Execution counters land in ``backend.stats``."""
+    k).  Execution counters land in ``backend.stats``.  A failing device
+    pass raises ``DeviceReplayError`` and is never turned into a numpy
+    result here: demotion on failure is the caller's decision."""
     if F.ndim != 2 or F.dtype != np.float64:
         raise ValueError("replay_accumulate expects a float64 (rows, k) "
                          f"matrix, got {F.dtype} ndim={F.ndim}")
@@ -725,26 +866,22 @@ def replay_accumulate(lv: LevelCSR, F: np.ndarray, quanta: np.ndarray,
     if b != "jax" or F.shape[1] == 0:
         stats.add("numpy_chunks")
         return _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
-    x64 = False
-    try:
-        import jax
-        if pol == "float64" and not jax.config.jax_enable_x64:
+    import jax
+    if pol == "float64":
+        if on_tpu():
+            raise ValueError(
+                "replay_dtype='float64' (or $EDAN_X64) asks for a float64 "
+                "device pass, which the TPU cannot run; use the default "
+                "float32 policy (exact by certificate) or backend='numpy'")
+        if not jax.config.jax_enable_x64:
             jax.config.update("jax_enable_x64", True)
-        x64 = bool(jax.config.jax_enable_x64)
-    except Exception:
-        stats.add("numpy_chunks")
-        return _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
-    if x64:
+    if _device_has_f64():
         # exact float64 on device (the opt-in x64 mode, or a process
-        # already running jax with the x64 flag)
-        try:
-            _accumulate_jax(lv, F, clamp=clamp, R_out=R_out)
-            stats.add("jax_chunks")
-            stats.add("jax_f64_chunks")
-            return F
-        except Exception:
-            stats.add("numpy_chunks")
-            return _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
+        # already running jax with the x64 flag, off the TPU)
+        _accumulate_jax(lv, F, clamp=clamp, R_out=R_out)
+        stats.add("jax_chunks")
+        stats.add("jax_f64_chunks")
+        return F
     # error-bounded float32 mode.  Pre-screen: only columns whose base
     # costs all sit strictly below the threshold go to the device.  This
     # is load-bearing for soundness, not just a fast path — the
@@ -769,11 +906,7 @@ def replay_accumulate(lv: LevelCSR, F: np.ndarray, quanta: np.ndarray,
     F32 = F[:, live_idx].astype(np.float32)
     R32 = (R_out[:, live_idx].astype(np.float32) if R_out is not None
            else None)
-    try:
-        _accumulate_jax(lv, F32, clamp=clamp, R_out=R32)
-    except Exception:
-        stats.add("numpy_chunks")
-        return _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
+    _accumulate_jax(lv, F32, clamp=clamp, R_out=R32)
     okl = _certified_f32(F32, quanta[live_idx], lv.n_levels)
     ok = np.zeros(F.shape[1], dtype=bool)
     ok[live_idx[okl]] = True

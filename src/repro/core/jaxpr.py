@@ -65,13 +65,24 @@ def _eqn_flops(eqn) -> float:
     return max(out_elems * _ELEMENTWISE_COST, 1.0)
 
 
-#: Call-like primitives whose sub-jaxpr is inlined transparently.  ``remat2``
-#: is jax's current name for the ``jax.checkpoint`` primitive — without it a
-#: checkpointed layer body collapses to one opaque vertex and whole-model
-#: traces lose all their memory parallelism.
-_CALL_PRIMS = ("pjit", "custom_jvp_call", "custom_vjp_call",
-               "custom_vjp_call_jaxpr", "custom_lin", "remat", "remat2",
-               "checkpoint", "closed_call", "core_call", "xla_call")
+def _call_jaxpr(eqn):
+    """The sub-jaxpr of a call-like equation, or None.
+
+    Any equation carrying a ``jaxpr`` / ``call_jaxpr`` parameter whose
+    inputs and outputs line up one-to-one with the equation's own is
+    inlined transparently: ``jit`` (``pjit`` in older jax), the custom
+    derivative calls, ``remat``/``checkpoint`` and friends.  Matching the
+    structure instead of primitive names keeps jitted and checkpointed
+    bodies transparent across jax renames.  Kernel calls such as
+    ``pallas_call`` carry a ref-based body whose arity differs (outputs are
+    written through refs), so they stay one opaque vertex."""
+    sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
+    inner = getattr(sub, "jaxpr", sub)
+    if (inner is None or not hasattr(inner, "eqns")
+            or len(inner.invars) != len(eqn.invars)
+            or len(inner.outvars) != len(eqn.outvars)):
+        return None
+    return sub
 
 
 def _jaxpr_cost(jaxpr, limit: int) -> float:
@@ -92,11 +103,10 @@ def _jaxpr_cost(jaxpr, limit: int) -> float:
                 total += max(_jaxpr_cost(getattr(b, "jaxpr", b), limit)
                              for b in branches)
                 continue
-        if prim in _CALL_PRIMS:
-            sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
-            if sub is not None:
-                total += _jaxpr_cost(getattr(sub, "jaxpr", sub), limit)
-                continue
+        sub = _call_jaxpr(eqn)
+        if sub is not None:
+            total += _jaxpr_cost(getattr(sub, "jaxpr", sub), limit)
+            continue
         total += _eqn_flops(eqn)
     return total
 
@@ -111,12 +121,10 @@ class _Builder:
     def run(self, jaxpr, env: Dict) -> Dict:
         for eqn in jaxpr.eqns:
             prim = eqn.primitive.name
-            sub = None
             if prim == "scan":
                 self._scan(eqn, env)
                 continue
-            if prim in _CALL_PRIMS:
-                sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
+            sub = _call_jaxpr(eqn)
             if prim == "cond":
                 # A static eDAG cannot keep both sides of a data-dependent
                 # branch, so emit the worst-case path: traverse every branch

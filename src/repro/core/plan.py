@@ -156,8 +156,10 @@ class ExecPolicy:
         """Execution rungs for degraded-mode retries, most capable first:
         the policy as requested, then exact x64 on the device backend
         (dodges f32-certificate demotion storms), then plain numpy (no
-        device at all).  Budget and cache policy carry through unchanged;
-        rungs equal to an earlier rung are dropped."""
+        device at all).  A TPU has no float64 kernels, so there the x64
+        rung is left out rather than run on numpy under its name.  Budget
+        and cache policy carry through unchanged; rungs equal to an
+        earlier rung are dropped."""
         rungs = [self,
                  ExecPolicy(backend="jax", replay_dtype="float64",
                             mem_budget=self.mem_budget,
@@ -165,8 +167,8 @@ class ExecPolicy:
                  ExecPolicy(backend="numpy", replay_dtype=None,
                             mem_budget=self.mem_budget,
                             use_cache=self.use_cache)]
-        if self.backend == "numpy":
-            del rungs[1]              # no device to demote onto
+        if self.backend == "numpy" or _bk.on_tpu():
+            del rungs[1]              # no float64 device rung to demote onto
         out: list = []
         for r in rungs:
             if r not in out:

@@ -10,21 +10,14 @@ module never touches jax device state.
 from __future__ import annotations
 
 import jax
-
-
-def auto_axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` where the installed jax has AxisType;
-    empty kwargs (the implicit default) on older releases."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return dict(axis_types=(axis_type.Auto,) * n_axes)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **auto_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
@@ -32,7 +25,7 @@ def make_host_mesh(model: int = 1):
     n = len(jax.devices())
     model = max(1, min(model, n))
     return jax.make_mesh((n // model, model), ("data", "model"),
-                         **auto_axis_types_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_axis_sizes(mesh) -> list:

@@ -25,19 +25,10 @@ from jax.sharding import PartitionSpec as P
 from ..configs.base import ModelConfig
 from ..sharding.rules import batch_axes_for, current_mesh
 
-try:
-    _shard_map = jax.shard_map
-except AttributeError:                                    # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def _smap(fn, mesh, in_specs, out_specs):
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _capacity(n_tokens: int, cfg: ModelConfig) -> int:

@@ -26,9 +26,11 @@ The service earns its keep in *how* it runs them:
 * **Bounded retries + degradation** — each stage retries up to
   ``max_retries`` (default ``$EDAN_MAX_RETRIES``, else 2) with
   exponential backoff.  Replay failures additionally walk the demotion
-  ladder — requested backend/dtype → jax float64 → numpy — so an
-  accelerator that stops certifying still yields exact numbers, just
-  slower; the policy actually used is reported per result.
+  ladder — requested backend/dtype → jax float64 (not on a TPU, which
+  has no float64 kernels) → numpy — so an accelerator that fails or
+  stops certifying still yields exact numbers, just slower; the policy
+  actually used is reported per result.  This ladder is the one place
+  that demotes: the kernel backend raises on a failed device pass.
 
 * **Poison isolation** — when a *union* replay keeps failing after the
   ladder, the batch is not failed wholesale: every member is re-run
@@ -266,10 +268,10 @@ def _error(code: str, stage: str, message: str, retries: int = 0) -> dict:
 def _demotion_ladder(backend: Optional[str], replay_dtype: Optional[str],
                      mem_budget: Optional[int] = None):
     """Replay policies in degradation order: what was asked for, then jax
-    with exact f64 (kills certificate trouble), then pure numpy (kills
-    the accelerator entirely).  Duplicates collapse so a numpy request
-    has a one-rung ladder.  Each rung is a resolved ``plan.ExecPolicy``
-    carrying the service's replay budget."""
+    with exact f64 (kills certificate trouble; left out on a TPU), then
+    pure numpy (kills the accelerator entirely).  Duplicates collapse so
+    a numpy request has a one-rung ladder.  Each rung is a resolved
+    ``plan.ExecPolicy`` carrying the service's replay budget."""
     return ExecPolicy.resolve(backend=backend, replay_dtype=replay_dtype,
                               mem_budget=mem_budget).ladder()
 

@@ -9,9 +9,9 @@ property-tested rather than hoped-for:
 
 * **Stages** — every service pipeline stage is an injection point
   (``load``, ``finalize``, ``schedule``, ``replay``, ``placement``,
-  ``report``, ``store``), plus two core hook points: ``kernel`` fires inside the jax
-  kernel path (``backend.fault_hook`` — exceptions there are swallowed
-  by the backend's own best-effort dispatch, proving the in-kernel
+  ``report``, ``store``), plus two core hook points: ``kernel`` fires
+  inside the jax kernel path (``backend.fault_hook`` — an exception
+  there propagates like a real device failure, up to the service's
   demotion ladder), and ``cache-load`` / ``cache-store`` fire inside the
   persistent schedule cache's disk IO.
 

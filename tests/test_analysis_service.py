@@ -220,17 +220,22 @@ def test_transient_replay_fault_demotes_and_recovers():
 
 
 def test_kernel_fault_degrades_inside_backend():
-    """A fault inside the jax kernel itself (backend.fault_hook) is
-    swallowed by the backend's own best-effort dispatch — the request
-    succeeds without even spending a service-level retry, bit-identical
-    to a clean run."""
+    """A fault inside the jax kernel itself (backend.fault_hook) is not
+    swallowed by the backend: it reaches the service's demotion ladder.
+    Firing on every device pass, it walks the request down to the numpy
+    rung, which reports the demotions and a result bit-identical to a
+    clean run."""
     if len(BACKENDS) < 2:
         pytest.skip("jax not available")
     faults.install("kernel", "backend")
     (res,) = svc().process([req(0, backend="jax")])
-    assert res.ok and res.policy["demotions"] == 0
+    assert res.ok
+    assert res.policy["backend"] == "numpy"
+    assert res.policy["demotions"] >= 1 and res.retries >= 1
+    assert faults.fire_log
     faults.reset()
     (clean,) = svc().process([req(0, backend="jax")])
+    assert clean.policy["demotions"] == 0
     assert_reports_equal(res.report, clean.report)
 
 

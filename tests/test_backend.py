@@ -8,10 +8,12 @@ comparison against the numpy kernel run on the same float32 inputs is
 exact equality, not a tolerance check.
 """
 import os
+from collections import OrderedDict
 
 import numpy as np
 import pytest
 
+from repro.core import backend as bk
 from repro.core import (EDag, level_accumulate, select_backend,
                         simulate_batch, simulate_reference)
 
@@ -329,3 +331,149 @@ def test_backend_and_cache_stats_are_thread_safe_maps():
 
     assert isinstance(backend_mod.stats, Stats)
     assert isinstance(sched_cache.stats, Stats)
+
+
+# ----------------------------- the device pass: tiling, failures, routing
+
+@pytest.fixture
+def x64_off():
+    was = bool(jax.config.jax_enable_x64)
+    jax.config.update("jax_enable_x64", False)
+    yield
+    jax.config.update("jax_enable_x64", was)
+
+
+def _int_case(seed: int = 3, k: int = 3):
+    g = _random_edag(seed, n=60)
+    rng = np.random.default_rng(seed)
+    base = rng.integers(1, 9, (g.n_vertices, k)).astype(np.float32)
+    return g._level_csr(), base
+
+
+def test_padded_rows_fill_whole_lanes():
+    lv, _ = _int_case()
+    gather, dsts = bk._jax_padded(lv)
+    assert gather.shape[1] % 128 == 0 and dsts.shape == gather.shape[:2]
+
+
+def test_row_block_tiles_the_level():
+    assert bk._row_block(256, 2, 11) == 256           # one block fits
+    tr = bk._row_block(22016, 7, 9)                   # a wide level
+    assert tr < 22016 and 22016 % tr == 0 and tr % 8 == 0
+    assert tr <= bk._ROW_TILE
+
+
+def test_pallas_row_blocks_bit_identical(monkeypatch):
+    """A level split over many grid steps gives the same bits as one."""
+    monkeypatch.setattr(bk, "_STEP_VMEM_BYTES", 1)   # 8-row blocks
+    monkeypatch.setattr(bk, "_JAX_CACHE", OrderedDict())
+    lv, base = _int_case()
+    F_np = level_accumulate(lv, base.copy(), backend="numpy")
+    F_jx = level_accumulate(lv, base.copy(), backend="jax")
+    assert np.array_equal(F_np, F_jx)
+
+
+def _entry(name, lv, F):
+    if name == "level_accumulate":
+        return level_accumulate(lv, F, backend="jax")
+    return bk.replay_accumulate(lv, F.astype(np.float64),
+                                np.ones(F.shape[1]), backend="jax",
+                                replay_dtype="float32")
+
+
+@pytest.mark.parametrize("entry", ["level_accumulate", "replay_accumulate"])
+def test_device_failure_propagates_with_plan_shape(monkeypatch, x64_off,
+                                                   entry):
+    """A device pass that fails (compile, run, memory) reaches the caller
+    naming the plan shape; nothing quietly runs on numpy instead."""
+    def broken(has_q, clamp, want_r):
+        def run(*args):
+            raise RuntimeError("RESOURCE_EXHAUSTED: injected")
+        return run
+
+    monkeypatch.setattr(bk, "_level_loop", broken)
+    monkeypatch.setattr(bk, "_JAX_CACHE", OrderedDict())
+    lv, base = _int_case()
+    bk.reset_stats()
+    with pytest.raises(bk.DeviceReplayError,
+                       match=r"\(L, Rmax, Dmax\)=.*RESOURCE_EXHAUSTED") as ei:
+        _entry(entry, lv, base)
+    assert isinstance(ei.value.__cause__, RuntimeError)
+    assert bk.stats["numpy_chunks"] == 0 and bk.stats["jax_chunks"] == 0
+
+
+@pytest.mark.parametrize("entry", ["level_accumulate", "replay_accumulate"])
+def test_kernel_fault_hook_propagates(monkeypatch, x64_off, entry):
+    class Boom(RuntimeError):
+        pass
+
+    def hook():
+        raise Boom("injected")
+
+    monkeypatch.setattr(bk, "fault_hook", hook)
+    lv, base = _int_case()
+    with pytest.raises(Boom):
+        _entry(entry, lv, base)
+
+
+def test_float64_routing_is_decided_up_front_and_counted(monkeypatch,
+                                                         x64_off):
+    """float64 input the device cannot run exactly goes to the numpy
+    kernel before any device call, and is counted: without the x64 flag,
+    and on a TPU even with it."""
+    lv, base = _int_case()
+    F64 = base.astype(np.float64)
+    want = level_accumulate(lv, F64.copy(), backend="numpy")
+    bk.reset_stats()
+    assert np.array_equal(level_accumulate(lv, F64.copy(), backend="jax"),
+                          want)
+    level_accumulate(lv, base.copy(), backend="jax")     # f32: on device
+    assert bk.stats["numpy_f64_passes"] == 1
+    monkeypatch.setattr(bk, "on_tpu", lambda: True)
+    monkeypatch.setattr(bk, "_accumulate_jax", None)     # never reached
+    jax.config.update("jax_enable_x64", True)
+    assert np.array_equal(level_accumulate(lv, F64.copy(), backend="jax"),
+                          want)
+    assert bk.stats["numpy_f64_passes"] == 2
+
+
+def test_float64_device_policy_refused_on_tpu(monkeypatch):
+    from repro.core.plan import ExecPolicy
+    lv, base = _int_case()
+    assert ("jax", "float64") in [(r.backend, r.replay_dtype)
+                                  for r in ExecPolicy().ladder()]
+    monkeypatch.setattr(bk, "on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="TPU"):
+        bk.replay_accumulate(lv, base.astype(np.float64),
+                             np.ones(base.shape[1]), backend="jax",
+                             replay_dtype="float64")
+    # the service ladder leaves the x64 rung out rather than run numpy
+    # under its name
+    rungs = [(r.backend, r.replay_dtype) for r in ExecPolicy().ladder()]
+    assert rungs == [(None, None), ("numpy", None)]
+
+
+@pytest.fixture
+def cache_dir_config():
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_dir_from_environment(monkeypatch, tmp_path,
+                                            cache_dir_config):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert bk.configure_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+
+def test_compile_cache_dir_defaults_to_checkout(monkeypatch,
+                                                cache_dir_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_dir", None)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(root, ".jax_cache")
+    assert bk.configure_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

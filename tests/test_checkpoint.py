@@ -9,7 +9,7 @@ import pytest
 
 from repro.train import checkpoint as ckpt
 from repro.train.fault import FaultTolerantLoop, StragglerStats
-from repro.launch.mesh import auto_axis_types_kwargs
+from jax.sharding import AxisType
 
 
 def tree():
@@ -54,7 +54,7 @@ def test_restore_with_shardings(tmp_path):
     """Elastic restore: device_put onto explicit shardings (re-shard path)."""
     t = tree()
     ckpt.save(t, str(tmp_path), step=1)
-    mesh = jax.make_mesh((1,), ("data",), **auto_axis_types_kwargs(1))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = jax.tree_util.tree_map(lambda _: NamedSharding(mesh, P()), t)
     got, _ = ckpt.restore(t, str(tmp_path), shardings=sh)

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.launch.mesh import auto_axis_types_kwargs
+from jax.sharding import AxisType
 from repro.train.compression import (compressed_psum_local, dequantize_int8,
                                      init_error_state, make_dp_train_step,
                                      quantize_int8)
@@ -37,7 +37,7 @@ def test_error_feedback_accumulates():
 
 def _mesh():
     return jax.make_mesh((jax.device_count(),), ("data",),
-                         **auto_axis_types_kwargs(1))
+                         axis_types=(AxisType.Auto,))
 
 
 def test_dp_train_step_compressed_matches_uncompressed():
@@ -77,22 +77,14 @@ def test_compressed_psum_local_single_device():
     """Inside shard_map on 1 device: payload == mean == input (+residual)."""
     mesh = _mesh()
     from jax.sharding import PartitionSpec as P
-    try:
-        smap = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as smap
 
     g = {"w": jnp.asarray(np.linspace(-1, 1, 32), jnp.float32)}
     e = init_error_state(g)
 
     def f(gl, el):
         return compressed_psum_local(gl, el, "data")
-    try:
-        out, err = smap(f, mesh=mesh, in_specs=(P(), P()),
-                        out_specs=(P(), P()), check_vma=False)(g, e)
-    except TypeError:
-        out, err = smap(f, mesh=mesh, in_specs=(P(), P()),
-                        out_specs=(P(), P()), check_rep=False)(g, e)
+    out, err = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                             out_specs=(P(), P()), check_vma=False)(g, e)
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),
                                atol=0.02)
     np.testing.assert_allclose(np.asarray(out["w"] + err["w"]),

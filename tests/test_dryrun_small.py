@@ -17,7 +17,7 @@ from repro.configs import ARCHS, TrainConfig
 from repro.core.hlo import analyze_collectives, hlo_flops_estimate, \
     hlo_hbm_bytes_estimate
 from repro.core.sensitivity import collective_sensitivity
-from repro.launch.mesh import auto_axis_types_kwargs
+from jax.sharding import AxisType
 from repro.models import get_model
 from repro.models.module import abstract_params
 from repro.sharding import param_partition_specs, sharding_ctx
@@ -25,7 +25,8 @@ from repro.sharding.rules import DEFAULT_RULES, decode_cache_rules
 from repro.train.optimizer import AdamState
 from repro.train.train_loop import make_train_step
 
-mesh = jax.make_mesh((2, 4), ("data", "model"), **auto_axis_types_kwargs(2))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 cfg = dataclasses.replace(ARCHS["qwen3-0.6b"].reduced(),
                           n_layers=3, d_model=128, n_heads=8, n_kv_heads=4,
                           head_dim=16, d_ff=256, vocab_size=512,

@@ -17,9 +17,10 @@ import jax, jax.numpy as jnp, numpy as np, dataclasses
 from repro.configs import ARCHS
 from repro.models import moe
 from repro.sharding.rules import sharding_ctx
-from repro.launch.mesh import auto_axis_types_kwargs
+from jax.sharding import AxisType
 
-mesh = jax.make_mesh((2, 4), ("data", "model"), **auto_axis_types_kwargs(2))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 cfg = dataclasses.replace(ARCHS["granite-moe-1b-a400m"].reduced(),
                           d_model=64, d_ff=32, n_experts=8, top_k=2,
                           capacity_factor=8.0)

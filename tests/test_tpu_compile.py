@@ -1,0 +1,95 @@
+"""The device level loop compiles for a TPU v5e at real plan shapes.
+
+Compiles the jitted level loop of the jax backend (``backend._level_loop``
+with its Pallas step, not interpreted) for one chip of a described v5e
+topology: the chip's compiler runs here without the chip.  It refuses
+what the chip would refuse — a kernel Mosaic cannot lower, a program
+that does not fit the device's memory — so these tests guard the device
+path at no chip time.  A passing compile says nothing about results or
+speed.
+
+Shapes are plans the chip smoke run drives, as ``_jax_padded`` pads them
+(the row axis to a multiple of 128, or of 512 past it): the PAPER_15
+PolyBench union at N=20, m=8 (both compute-slot variants merged, 11
+alphas; 449 rows wide before padding), the HPCG CG trace at n=8, 3
+iterations (3 alphas), and the service's union of kernel, CG and model
+traces, whose analytic sweep has levels 22,016 rows wide (9 alphas).
+Each is compiled under the two flag sets in use: the batched simulator's
+replay (slot chains and ready times, no clamp) and the analytic sweep
+(clamp, no slot chain, no ready times).  Each compile must hold the
+Pallas kernel and keep device temp under 1 GiB: a gather tensor whose
+row axis is off the lane width is relaid out whole on every call.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and test workers import
+every test file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import backend as bk
+
+# (name, rows, L, Rmax, Dmax, k)
+PLANS = [
+    ("paper15_m8", 1_108_760, 34_506, 512, 2, 11),
+    ("hpcg_n8_iters3", 104_342, 14_928, 1024, 2, 3),
+    ("service_union", 216_331, 5_247, 22_016, 7, 9),
+]
+# (has_q, clamp, want_r)
+FLAGS = [
+    pytest.param((True, False, True), id="replay"),
+    pytest.param((False, True, False), id="sweep"),
+]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs to /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_for_chip(monkeypatch):
+    """Compile the Pallas step for the chip (not interpreted) and keep
+    the persistent compilation cache out of it: an entry compiled for a
+    described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    monkeypatch.setattr(bk, "_pallas_interpret", lambda: False)
+    was = bool(jax.config.jax_enable_compilation_cache)
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("flags", FLAGS)
+@pytest.mark.parametrize("plan", PLANS, ids=[p[0] for p in PLANS])
+def test_level_loop_compiles_for_v5e(one_chip, compiled_for_chip, plan,
+                                     flags):
+    _, rows, L, rmax, dmax, k = plan
+    has_q, clamp, want_r = flags
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n = rows + 1 if has_q else rows     # slot chains add a sentinel row
+    args = (arg((n, k), jnp.float32),
+            arg((n, k) if want_r else (1, k), jnp.float32),
+            arg((L, rmax, dmax), jnp.int32),
+            arg((L, rmax), jnp.int32),
+            arg((rows,) if has_q else (1,), jnp.int32))
+    run = bk._level_loop(has_q, clamp, want_r)
+    compiled = jax.jit(run).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
