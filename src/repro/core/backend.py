@@ -58,7 +58,7 @@ from typing import Optional
 
 import numpy as np
 
-from .counters import Stats
+from .counters import Stats, span
 
 _BACKENDS = ("numpy", "jax")
 _AUTO_BACKEND: Optional[str] = None
@@ -346,6 +346,11 @@ def levelize(src: np.ndarray, dst: np.ndarray, n: int) -> np.ndarray:
     bounded chunks — a boxed-int list of a million-vertex level vector
     (or a full ``tolist()`` of its edges) holds hundreds of MB of int
     objects at once."""
+    with span("levelize", vertices=n, edges=len(dst)):
+        return _levelize(src, dst, n)
+
+
+def _levelize(src, dst, n: int) -> np.ndarray:
     out = np.zeros(n, dtype=np.int32)
     if len(dst):
         src = np.asarray(src)
@@ -436,6 +441,13 @@ _JAX_CACHE_CAP = 8
 _ROW_TILE = 512
 _STEP_VMEM_BYTES = 8 * 1024 * 1024
 
+#: Name of the level loop's compiled program, as the profiler's trace
+#: and the compiler's dumps show it: ``jax.jit`` names a program
+#: ``jit_`` + the traced function's ``__name__``, which ``_level_loop``
+#: sets from this constant.  Trace readers select the loop's device time
+#: by it; renaming it leaves them nothing to match.
+LEVEL_LOOP_NAME = "jit_run"
+
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
@@ -478,6 +490,12 @@ def _jax_padded(lv: LevelCSR):
     union plan)."""
     if lv.jax_padded is not None:
         return lv.jax_padded
+    with span("replay.pad", levels=max(lv.n_levels - 1, 0)):
+        lv.jax_padded = _pad_levels(lv)
+    return lv.jax_padded
+
+
+def _pad_levels(lv: LevelCSR):
     L = lv.n_levels
     rcounts = np.diff(lv.run_ptr)
     qcounts = (np.diff(lv.qonly_ptr) if lv.qonly_ptr is not None
@@ -499,8 +517,7 @@ def _jax_padded(lv: LevelCSR):
         if lv.qonly_ptr is not None:
             q0, q1 = lv.qonly_ptr[lvl], lv.qonly_ptr[lvl + 1]
             dsts[lvl, r1 - r0:r1 - r0 + (q1 - q0)] = lv.qonly_dst[q0:q1]
-    lv.jax_padded = (gather, dsts)
-    return lv.jax_padded
+    return gather, dsts
 
 
 def _pallas_interpret() -> bool:
@@ -609,6 +626,7 @@ def _level_loop(has_q: bool, clamp: bool, want_r: bool):
 
         return jax.lax.fori_loop(1, L, body, (Fin, Rin))
 
+    run.__name__ = run.__qualname__ = LEVEL_LOOP_NAME[len("jit_"):]
     return run
 
 
@@ -662,14 +680,25 @@ def _accumulate_jax(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
     _JAX_CACHE.move_to_end(key)
     while len(_JAX_CACHE) > _JAX_CACHE_CAP:
         _JAX_CACHE.popitem(last=False)
+    L, Rmax, Dmax = gather.shape
+    moved = F.nbytes + (R_out.nbytes if want_r else 0)
     try:
-        Rin = jnp.asarray(R_out) if want_r else jnp.zeros(
-            (1, F.shape[1]), dtype=F.dtype)
-        Fj, Rj = fn(jnp.asarray(F), Rin, jnp.asarray(gather),
+        with span("replay.upload", bytes=moved + gather.nbytes + dsts.nbytes
+                  + qp.nbytes):
+            Rin = jnp.asarray(R_out) if want_r else jnp.zeros(
+                (1, F.shape[1]), dtype=F.dtype)
+            args = (jnp.asarray(F), Rin, jnp.asarray(gather),
                     jnp.asarray(dsts), jnp.asarray(qp))
-        F[:] = np.asarray(Fj)
-        if want_r:
-            R_out[:] = np.asarray(Rj)
+        # the call returns once the loop is dispatched; the download
+        # below waits for the device to finish it
+        with span("replay.run", levels=max(L - 1, 0), rows=Rmax,
+                  width=Dmax, edges=len(lv.esrc),
+                  slots=max(L - 1, 0) * Rmax * Dmax):
+            Fj, Rj = fn(*args)
+        with span("replay.download", bytes=moved):
+            F[:] = np.asarray(Fj)
+            if want_r:
+                R_out[:] = np.asarray(Rj)
     except Exception as exc:
         raise DeviceReplayError(
             f"device level pass failed on {jax.default_backend()} for plan "
@@ -856,6 +885,16 @@ def replay_accumulate(lv: LevelCSR, F: np.ndarray, quanta: np.ndarray,
     if quanta.shape != (F.shape[1],):
         raise ValueError("quanta must have one entry per column")
     stats.add("chunks")
+    with span("replay", levels=max(lv.n_levels - 1, 0), rows=F.shape[0],
+              columns=F.shape[1]):
+        return _replay(lv, F, quanta, clamp, R_out, backend, replay_dtype)
+
+
+def _replay(lv: LevelCSR, F: np.ndarray, quanta: np.ndarray, clamp: bool,
+            R_out: Optional[np.ndarray], backend: Optional[str],
+            replay_dtype: Optional[str]) -> np.ndarray:
+    """``replay_accumulate`` past its argument checks; each host stage of
+    the float32 mode is a span of its own (``replay.*``)."""
     b = select_backend(backend)
     # an explicit replay_dtype argument is validated on every backend (a
     # typo'd argument is a caller bug and must not surface only once the
@@ -895,19 +934,23 @@ def replay_accumulate(lv: LevelCSR, F: np.ndarray, quanta: np.ndarray,
     # (clamp off, nonneg bases) a base past the threshold also forces
     # the makespan past it, so nothing certifiable is ever screened off;
     # for clamped sweeps the screen is merely conservative.
-    thr = _f32_thresholds(quanta, lv.n_levels)
-    base_mag = np.abs(F).max(axis=0) if len(F) else np.zeros(F.shape[1])
-    live = base_mag < thr
-    live_idx = np.flatnonzero(live)
+    with span("replay.prescreen"):
+        thr = _f32_thresholds(quanta, lv.n_levels)
+        base_mag = (np.abs(F).max(axis=0) if len(F)
+                    else np.zeros(F.shape[1]))
+        live_idx = np.flatnonzero(base_mag < thr)
     if len(live_idx) == 0:
         stats.add("numpy_chunks")
         stats.add("demoted_columns", F.shape[1])
-        return _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
-    F32 = F[:, live_idx].astype(np.float32)
-    R32 = (R_out[:, live_idx].astype(np.float32) if R_out is not None
-           else None)
+        with span("replay.demote", columns=F.shape[1]):
+            return _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
+    with span("replay.cast"):
+        F32 = F[:, live_idx].astype(np.float32)
+        R32 = (R_out[:, live_idx].astype(np.float32) if R_out is not None
+               else None)
     _accumulate_jax(lv, F32, clamp=clamp, R_out=R32)
-    okl = _certified_f32(F32, quanta[live_idx], lv.n_levels)
+    with span("replay.certify"):
+        okl = _certified_f32(F32, quanta[live_idx], lv.n_levels)
     ok = np.zeros(F.shape[1], dtype=bool)
     ok[live_idx[okl]] = True
     n_ok = int(okl.sum())
@@ -917,21 +960,25 @@ def replay_accumulate(lv: LevelCSR, F: np.ndarray, quanta: np.ndarray,
         # the numpy kernel runs in place — no slice copies needed
         stats.add("numpy_chunks")
         stats.add("demoted_columns", F.shape[1])
-        return _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
+        with span("replay.demote", columns=F.shape[1]):
+            return _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
     # certified columns are exact multiples of q below 2^24 * q — the
     # float32 values ARE the float64 values, the cast is lossless
-    F[:, ok] = F32[:, okl]
-    if R_out is not None:
-        R_out[:, ok] = R32[:, okl]
+    with span("replay.merge"):
+        F[:, ok] = F32[:, okl]
+        if R_out is not None:
+            R_out[:, ok] = R32[:, okl]
     stats.add("jax_chunks")
     bad = ~ok
-    if bad.any():
-        stats.add("demoted_columns", int(bad.sum()))
-        Fb = np.ascontiguousarray(F[:, bad])
-        Rb = (np.ascontiguousarray(R_out[:, bad]) if R_out is not None
-              else None)
-        _accumulate_numpy(lv, Fb, clamp=clamp, R_out=Rb)
-        F[:, bad] = Fb
-        if R_out is not None:
-            R_out[:, bad] = Rb
+    n_bad = F.shape[1] - n_ok
+    if n_bad:
+        stats.add("demoted_columns", n_bad)
+        with span("replay.demote", columns=n_bad):
+            Fb = np.ascontiguousarray(F[:, bad])
+            Rb = (np.ascontiguousarray(R_out[:, bad]) if R_out is not None
+                  else None)
+            _accumulate_numpy(lv, Fb, clamp=clamp, R_out=Rb)
+            F[:, bad] = Fb
+            if R_out is not None:
+                R_out[:, bad] = Rb
     return F

@@ -19,10 +19,37 @@ funnelling every mutation through one lock:
 
 Unknown keys raise ``KeyError`` on ``add`` — a typo'd counter name is a
 bug worth surfacing, not a silently growing new key.
+
+``span(name, **counts)`` names one stage of a query on the profiler's
+clock: it opens ``jax.profiler.TraceAnnotation("edan." + name,
+**counts)``, so the stage and its integer counts (bytes moved, levels
+replayed, ...) appear as a host event with those stats in any trace
+captured around the query (``jax.profiler.trace``), beside the device's
+own events.  With no trace active the profiler drops the event; entering
+and leaving costs about a microsecond.  Spans sit at stage boundaries
+only, never per level, vertex or edge, and their counts are attribute
+reads (``len``, ``.nbytes``, ``.shape``).  The spans the engine opens are
+listed in ``docs/ARCHITECTURE.md`` ("Profiling a query").
 """
 from __future__ import annotations
 
 import threading
+
+#: Prefix of every span name the engine opens.
+SPAN_PREFIX = "edan."
+
+_annotation = None              # jax.profiler.TraceAnnotation, once imported
+
+
+def span(name: str, **counts):
+    """Context manager naming one query stage in the profiler's trace as
+    ``edan.<name>`` with ``counts`` as its stats; returns the annotation,
+    whose ``set_metadata(**counts)`` adds stats known only inside."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(SPAN_PREFIX + name, **counts)
 
 
 class Stats:

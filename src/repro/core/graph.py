@@ -50,6 +50,8 @@ import numpy as np
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
+from .counters import span
+
 # Below this many edges per level on average the per-level numpy dispatch
 # overhead exceeds the Python loop cost (deep, skinny DAGs such as forward
 # substitution); fall back to the scalar kernel there.
@@ -911,16 +913,18 @@ class EDag:
         lv = self._level_csr()
         out = []
         for i in range(0, len(alphas), chunk):
-            if cls is not None:
-                F = np.where(self.is_mem[:, None],
-                             alphas[i:i + chunk].T[cls], float(unit))
-            else:
-                F = np.where(self.is_mem[:, None],
-                             alphas[None, i:i + chunk], float(unit))
-            pol.accumulate(lv, F,
-                           column_quanta(alphas[i:i + chunk], unit),
-                           clamp=True)
-            out.append(F.max(axis=0))
+            with span("fill", rows=self.n_vertices,
+                      columns=len(alphas[i:i + chunk])):
+                if cls is not None:
+                    F = np.where(self.is_mem[:, None],
+                                 alphas[i:i + chunk].T[cls], float(unit))
+                else:
+                    F = np.where(self.is_mem[:, None],
+                                 alphas[None, i:i + chunk], float(unit))
+                quanta = column_quanta(alphas[i:i + chunk], unit)
+            pol.accumulate(lv, F, quanta, clamp=True)
+            with span("reduce"):
+                out.append(F.max(axis=0))
         return np.concatenate(out)
 
     def start_finish(self, cost: Optional[np.ndarray] = None):

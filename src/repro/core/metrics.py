@@ -7,6 +7,7 @@ from typing import Optional
 import numpy as np
 
 from .cost import CostModelParams, non_memory_cost
+from .counters import span
 from .graph import EDag
 from .plan import ExecPolicy, SweepSpec
 
@@ -241,41 +242,49 @@ def grid_report(g: EDag, alphas, ms=(4,), compute_slots=(0,),
     from .cost import non_memory_cost
     from .scheduler import _sweep_grid_spec
 
-    pol = ExecPolicy.resolve(backend=backend, replay_dtype=replay_dtype,
-                             mem_budget=mem_budget, use_cache=use_cache,
-                             policy=policy)
-    spec = SweepSpec.make(alphas, ms=ms, compute_slots=compute_slots,
-                          unit=params.unit)
-    g._finalize()
-    alphas = spec.alphas
-    ms_arr = np.asarray(spec.ms, dtype=np.int64)
-    css = np.asarray(spec.css, dtype=np.int64)
-    lay = g.mem_layers()
-    W, D = lay.W, lay.D
-    C = non_memory_cost(g, params.unit)
-    lam = lambda_abs(W, D, ms_arr)                         # Eq 3, per m
-    t_inf = t_inf_sweep(g, alphas, params.unit, policy=pol)
-    if alphas.ndim == 2:
-        # class rows: the scalar bounds hold at the extreme class alphas
-        # of each row, bracketing every per-vertex class assignment
-        if alphas.shape[1]:
-            a_lo, a_hi = alphas.min(axis=1), alphas.max(axis=1)
-        else:
-            a_lo = a_hi = np.zeros(len(alphas))
-    else:
-        a_lo = a_hi = alphas
-    # Eq 1-2 bounds and Eq 4 Lambda over the (alpha, m) grid in one shot
-    mem_lo = np.maximum(D, W / ms_arr)[None, :] * a_lo[:, None]
-    mem_hi = lam[None, :] * a_hi[:, None]
-    denom = mem_hi + C
-    Lam = np.divide(lam[None, :], denom,
-                    out=np.zeros_like(denom), where=denom > 0)
-    out = dict(alphas=alphas, ms=ms_arr, compute_slots=css,
-               W=W, D=D, C=C, lam=lam, Lam=Lam, t_inf=t_inf,
-               t_lower=mem_lo + C, t_upper=mem_hi + C)
-    if simulate_points:
-        out["simulated"] = _sweep_grid_spec(g, spec, pol)
-    return out
+    with span("query", entry="grid_report"):
+        pol = ExecPolicy.resolve(backend=backend, replay_dtype=replay_dtype,
+                                 mem_budget=mem_budget, use_cache=use_cache,
+                                 policy=policy)
+        spec = SweepSpec.make(alphas, ms=ms, compute_slots=compute_slots,
+                              unit=params.unit)
+        g._finalize()
+        alphas = spec.alphas
+        with span("report"):
+            ms_arr = np.asarray(spec.ms, dtype=np.int64)
+            css = np.asarray(spec.css, dtype=np.int64)
+            lay = g.mem_layers()
+            W, D = lay.W, lay.D
+            C = non_memory_cost(g, params.unit)
+            lam = lambda_abs(W, D, ms_arr)                 # Eq 3, per m
+        t_inf = t_inf_sweep(g, alphas, params.unit, policy=pol)
+        with span("report"):
+            a_lo, a_hi = _alpha_extremes(alphas)
+            # Eq 1-2 bounds and Eq 4 Lambda over the (alpha, m) grid in
+            # one shot
+            mem_lo = np.maximum(D, W / ms_arr)[None, :] * a_lo[:, None]
+            mem_hi = lam[None, :] * a_hi[:, None]
+            denom = mem_hi + C
+            Lam = np.divide(lam[None, :], denom,
+                            out=np.zeros_like(denom), where=denom > 0)
+            out = dict(alphas=alphas, ms=ms_arr, compute_slots=css,
+                       W=W, D=D, C=C, lam=lam, Lam=Lam, t_inf=t_inf,
+                       t_lower=mem_lo + C, t_upper=mem_hi + C)
+        if simulate_points:
+            out["simulated"] = _sweep_grid_spec(g, spec, pol)
+        return out
+
+
+def _alpha_extremes(alphas: np.ndarray):
+    """The smallest and largest alpha of each point: the point itself
+    for scalar alphas; for class rows the extreme class alphas, at which
+    the scalar bounds bracket every per-vertex class assignment."""
+    if alphas.ndim != 2:
+        return alphas, alphas
+    if not alphas.shape[1]:
+        zero = np.zeros(len(alphas))
+        return zero, zero
+    return alphas.min(axis=1), alphas.max(axis=1)
 
 
 def suite_grid_report(suite, alphas, ms=(4,), compute_slots=(0,),
@@ -304,50 +313,51 @@ def suite_grid_report(suite, alphas, ms=(4,), compute_slots=(0,),
     """
     from .suite import _suite_sweep_grid_spec, suite_t_inf_sweep
 
-    pol = ExecPolicy.resolve(backend=backend, replay_dtype=replay_dtype,
-                             mem_budget=mem_budget, use_cache=use_cache,
-                             policy=policy)
-    spec = SweepSpec.make(alphas, ms=ms, compute_slots=compute_slots,
-                          unit=params.unit)
-    alphas = spec.alphas
-    ms_arr = np.asarray(spec.ms, dtype=np.int64)
-    css = np.asarray(spec.css, dtype=np.int64)
-    K = suite.n_traces
-    if K and suite.n_vertices:
-        u = suite.union
-        lay = u.mem_layers()                       # one union level pass
-        W = suite.segment_sum(u.is_mem.astype(np.float64)).astype(np.int64)
-        D = suite.segment_max(lay.level).astype(np.int64)
-        counts = np.diff(suite.offsets)
-        C = (counts - W) * params.unit
-        t_inf = suite_t_inf_sweep(suite, alphas, params.unit, policy=pol)
-    else:
-        W = D = np.zeros(K, dtype=np.int64)
-        C = np.zeros(K)
-        t_inf = np.zeros((K, len(alphas)))
-    lam = lambda_abs(W[:, None].astype(np.float64), D[:, None], ms_arr)
-    if alphas.ndim == 2:
-        # class rows bracket per-vertex assignments (see grid_report)
-        if alphas.shape[1]:
-            a_lo, a_hi = alphas.min(axis=1), alphas.max(axis=1)
+    with span("query", entry="suite_grid_report"):
+        pol = ExecPolicy.resolve(backend=backend, replay_dtype=replay_dtype,
+                                 mem_budget=mem_budget, use_cache=use_cache,
+                                 policy=policy)
+        spec = SweepSpec.make(alphas, ms=ms, compute_slots=compute_slots,
+                              unit=params.unit)
+        alphas = spec.alphas
+        ms_arr = np.asarray(spec.ms, dtype=np.int64)
+        css = np.asarray(spec.css, dtype=np.int64)
+        K = suite.n_traces
+        if K and suite.n_vertices:
+            with span("report"):
+                u = suite.union
+                lay = u.mem_layers()               # one union level pass
+                W = suite.segment_sum(
+                    u.is_mem.astype(np.float64)).astype(np.int64)
+                D = suite.segment_max(lay.level).astype(np.int64)
+                counts = np.diff(suite.offsets)
+                C = (counts - W) * params.unit
+            t_inf = suite_t_inf_sweep(suite, alphas, params.unit,
+                                      policy=pol)
         else:
-            a_lo = a_hi = np.zeros(len(alphas))
-    else:
-        a_lo = a_hi = alphas
-    # Eq 1-2 bounds and Eq 4 Lambda over the (trace, alpha, m) grid
-    mem_lo = np.maximum(D[:, None], W[:, None] / ms_arr)[:, None, :] * \
-        a_lo[None, :, None]
-    mem_hi = lam[:, None, :] * a_hi[None, :, None]
-    denom = mem_hi + C[:, None, None]
-    Lam = np.divide(lam[:, None, :], denom,
-                    out=np.zeros_like(denom), where=denom > 0)
-    out = dict(names=list(suite.names), alphas=alphas, ms=ms_arr,
-               compute_slots=css, W=W, D=D, C=C, lam=lam, Lam=Lam,
-               t_inf=t_inf, t_lower=mem_lo + C[:, None, None],
-               t_upper=mem_hi + C[:, None, None])
-    if simulate_points:
-        out["simulated"] = _suite_sweep_grid_spec(suite, spec, pol)
-    return out
+            W = D = np.zeros(K, dtype=np.int64)
+            C = np.zeros(K)
+            t_inf = np.zeros((K, len(alphas)))
+        with span("report"):
+            lam = lambda_abs(W[:, None].astype(np.float64), D[:, None],
+                             ms_arr)
+            a_lo, a_hi = _alpha_extremes(alphas)
+            # Eq 1-2 bounds and Eq 4 Lambda over the (trace, alpha, m)
+            # grid
+            mem_lo = np.maximum(D[:, None],
+                                W[:, None] / ms_arr)[:, None, :] * \
+                a_lo[None, :, None]
+            mem_hi = lam[:, None, :] * a_hi[None, :, None]
+            denom = mem_hi + C[:, None, None]
+            Lam = np.divide(lam[:, None, :], denom,
+                            out=np.zeros_like(denom), where=denom > 0)
+            out = dict(names=list(suite.names), alphas=alphas, ms=ms_arr,
+                       compute_slots=css, W=W, D=D, C=C, lam=lam, Lam=Lam,
+                       t_inf=t_inf, t_lower=mem_lo + C[:, None, None],
+                       t_upper=mem_hi + C[:, None, None])
+        if simulate_points:
+            out["simulated"] = _suite_sweep_grid_spec(suite, spec, pol)
+        return out
 
 
 def report(g: EDag, params: CostModelParams = CostModelParams()) -> Report:

@@ -127,13 +127,12 @@ def _delta_decode(deltas: np.ndarray) -> Optional[np.ndarray]:
 #: ``simulate_batch``; ``record_runs`` counts instrumented event-loop
 #: recordings (the cost the cache exists to amortize); ``stores`` counts
 #: successful disk writes; ``quarantined`` counts corrupt entries moved
-#: aside to ``*.bad`` on load; ``record_seconds`` accumulates wall-clock
-#: seconds spent inside instrumented recordings — the quantity a warm
-#: cache amortizes (benchmarks assert it is 0.0 in warm processes).
+#: aside to ``*.bad`` on load.  The time of each recording is the span
+#: ``edan.schedule.record`` in a profiler trace (``counters.span``).
 #: Thread-safe (``counters.Stats``): the analysis service warms this
 #: cache from concurrent batches.
 stats = Stats(memory_hits=0, disk_hits=0, misses=0, stores=0,
-              record_runs=0, quarantined=0, record_seconds=0.0)
+              record_runs=0, quarantined=0)
 
 #: Fault-injection hook (``serve.faults``): when set, called with the
 #: point name (``"cache-load"`` / ``"cache-store"``) before disk IO so

@@ -62,13 +62,13 @@ through the level kernel instead of materializing (n, |grid|) matrices.
 from __future__ import annotations
 
 import heapq
-import time
 from typing import Optional
 
 import numpy as np
 
 from . import backend as _bk
 from . import schedule_cache as _sc
+from .counters import span
 from .graph import EDag
 from .plan import (REPLAY_BYTES_PER_CELL, REPLAY_MEM_BUDGET, ExecPolicy,
                    SweepSpec, replay_mem_budget)
@@ -486,17 +486,18 @@ class _ReplayPlan:
         gather, same stacked kernel."""
         pol = ExecPolicy.resolve(policy=policy)
         k = len(alphas)
-        F = np.empty((self.n + 1, k))
-        if alphas.ndim == 2:
-            F[:-1] = np.where(self.is_mem_topo[:, None],
-                              alphas.T[self.cls_topo], unit)
-        else:
-            F[:-1] = np.where(self.is_mem_topo[:, None],
-                              alphas[None, :], unit)
-        F[-1] = 0.0
-        R = np.zeros_like(F)
-        pol.accumulate(self.lv, F, _bk.column_quanta(alphas, unit),
-                       clamp=False, R_out=R)
+        with span("fill", rows=self.n + 1, columns=k):
+            F = np.empty((self.n + 1, k))
+            if alphas.ndim == 2:
+                F[:-1] = np.where(self.is_mem_topo[:, None],
+                                  alphas.T[self.cls_topo], unit)
+            else:
+                F[:-1] = np.where(self.is_mem_topo[:, None],
+                                  alphas[None, :], unit)
+            F[-1] = 0.0
+            R = np.zeros_like(F)
+            quanta = _bk.column_quanta(alphas, unit)
+        pol.accumulate(self.lv, F, quanta, clamp=False, R_out=R)
         return F, R
 
     def array_nbytes(self) -> dict:
@@ -707,36 +708,38 @@ def _get_plan(g: EDag, m: int, cs: int,
     """Look up a reusable replay plan: per-process memo, then disk."""
     key = (m, cs, float(unit))
     memo = getattr(g, "_replay_plans", None)
-    if memo is not None and key in memo:
-        memo.move_to_end(key)
-        _sc.stats.add("memory_hits")
-        return memo[key]
-    if g.n_vertices >= _sc.min_vertices():
-        got = _sc.load(g.trace_digest(), m, cs, g.n_vertices, unit)
-        if got is not None:
-            plan = _plan_from_cache(g, m, cs, *got)
+    hit = memo is not None and key in memo
+    with span("plan", hit=int(hit)):
+        if hit:
+            memo.move_to_end(key)
+            _sc.stats.add("memory_hits")
+            return memo[key]
+        if g.n_vertices >= _sc.min_vertices():
+            with span("schedule.load") as sp:
+                got = _sc.load(g.trace_digest(), m, cs, g.n_vertices, unit)
+                plan = (_plan_from_cache(g, m, cs, *got)
+                        if got is not None else None)
+                sp.set_metadata(hit=int(plan is not None))
             if plan is not None:
                 _sc.stats.add("disk_hits")
                 _memo_plan(g, key, plan)
                 return plan
-    _sc.stats.add("misses")
-    return None
+        _sc.stats.add("misses")
+        return None
 
 
 def _record_plan(g: EDag, sim_lists, m: int, cs: int, a0: float,
                  unit: float, persist: bool):
     """One instrumented reference run -> (master makespan, replay plan);
     the plan is memoized and, for large traces, persisted to disk.  The
-    serial recording cost (event loop + plan build) is accumulated into
-    ``schedule_cache.stats["record_seconds"]`` — the number a warm cache
-    amortizes, reported by the cache bench and asserted zero for warm
-    processes in CI."""
+    serial recording (event loop + plan build) is the span
+    ``edan.schedule.record``: the cost a warm cache amortizes, which
+    ``schedule_cache.stats["record_runs"]`` counts."""
     _sc.stats.add("record_runs")
-    t0 = time.perf_counter()
-    mk0, topo, O_mem, O_alu = _event_loop(
-        g.is_mem, sim_lists, m, a0, unit, cs, record=True)
-    plan = _ReplayPlan(g, topo, O_mem, O_alu, m, cs)
-    _sc.stats.add("record_seconds", time.perf_counter() - t0)
+    with span("schedule.record", vertices=g.n_vertices):
+        mk0, topo, O_mem, O_alu = _event_loop(
+            g.is_mem, sim_lists, m, a0, unit, cs, record=True)
+        plan = _ReplayPlan(g, topo, O_mem, O_alu, m, cs)
     if persist:
         _memo_plan(g, (m, cs, float(unit)), plan)
         if g.n_vertices >= _sc.min_vertices():
@@ -781,8 +784,9 @@ def _batch_uniq(g: EDag, alphas: np.ndarray, m: int, cs: int, unit: float,
         reused = plan is not None and mk0 is None
         if plan is None:
             a0 = float(alphas[remaining[0]])
-            mk0, plan = _record_plan(g, sim_lists, m, cs, a0, unit,
-                                     persist=persist)
+            with span("plan", hit=0):
+                mk0, plan = _record_plan(g, sim_lists, m, cs, a0, unit,
+                                         persist=persist)
             # only the sweep's first recording is worth keeping: later
             # ones are per-point fallbacks for tie-shifted orders and
             # would thrash the cache with alpha-specific schedules
@@ -792,12 +796,15 @@ def _batch_uniq(g: EDag, alphas: np.ndarray, m: int, cs: int, unit: float,
         for c0 in range(0, remaining.size, chunk):
             sel = remaining[c0:c0 + chunk]
             F, R = plan.replay(alphas[sel], unit, policy=pol)
-            okc = _verify_class(g, plan.rank, F, R, plan.O_mem, plan.Om_rel)
-            if cs:
-                okc &= _verify_class(g, plan.rank, F, R, plan.O_alu,
-                                     plan.Oa_rel)
-            mk = F.max(axis=0)
-            out[sel[okc]] = mk[okc]
+            with span("verify"):
+                okc = _verify_class(g, plan.rank, F, R, plan.O_mem,
+                                    plan.Om_rel)
+                if cs:
+                    okc &= _verify_class(g, plan.rank, F, R, plan.O_alu,
+                                         plan.Oa_rel)
+            with span("reduce"):
+                mk = F.max(axis=0)
+                out[sel[okc]] = mk[okc]
             ok[c0:c0 + chunk] = okc
         if not ok[0] and mk0 is not None:
             # the master's own schedule always certifies; if the check ever
@@ -838,23 +845,25 @@ def _batch_uniq_classes(g: EDag, alphas: np.ndarray, m: int, cs: int,
     key = ("classes", m, cs, float(unit), g.mem_class_digest())
     plan = None
     memo = getattr(g, "_replay_plans", None)
-    if pol.use_cache and memo is not None and key in memo:
-        memo.move_to_end(key)
-        _sc.stats.add("memory_hits")
-        plan = memo[key]
+    if pol.use_cache:
+        hit = memo is not None and key in memo
+        with span("plan", hit=int(hit)):
+            if hit:
+                memo.move_to_end(key)
+                _sc.stats.add("memory_hits")
+                plan = memo[key]
     mk0: Optional[float] = None
     persist = pol.use_cache and plan is None
     while remaining.size:
         reused = plan is not None and mk0 is None
         if plan is None:
             _sc.stats.add("record_runs")
-            t0 = time.perf_counter()
-            mk0, topo, O_mem, O_alu, prov = _event_loop_classes(
-                g.is_mem, sim_lists, m, alphas[remaining[0]], cls, unit,
-                cs, record=True)
-            plan = _ReplayPlan(g, topo, O_mem, O_alu, m, cs,
-                               prov=prov, classes=cls)
-            _sc.stats.add("record_seconds", time.perf_counter() - t0)
+            with span("plan", hit=0), span("schedule.record", vertices=n):
+                mk0, topo, O_mem, O_alu, prov = _event_loop_classes(
+                    g.is_mem, sim_lists, m, alphas[remaining[0]], cls, unit,
+                    cs, record=True)
+                plan = _ReplayPlan(g, topo, O_mem, O_alu, m, cs,
+                                   prov=prov, classes=cls)
             if persist:
                 _memo_plan(g, key, plan)
             persist = False
@@ -863,14 +872,16 @@ def _batch_uniq_classes(g: EDag, alphas: np.ndarray, m: int, cs: int,
         for c0 in range(0, remaining.size, chunk):
             sel = remaining[c0:c0 + chunk]
             F, R = plan.replay(alphas[sel], unit, policy=pol)
-            okc = _verify_class(g, plan.rank, F, R, plan.O_mem,
-                                plan.Om_rel)
-            okc &= _verify_slots(plan, F)
-            if cs:
-                okc &= _verify_class(g, plan.rank, F, R, plan.O_alu,
-                                     plan.Oa_rel)
-            mk = F.max(axis=0)
-            out[sel[okc]] = mk[okc]
+            with span("verify"):
+                okc = _verify_class(g, plan.rank, F, R, plan.O_mem,
+                                    plan.Om_rel)
+                okc &= _verify_slots(plan, F)
+                if cs:
+                    okc &= _verify_class(g, plan.rank, F, R, plan.O_alu,
+                                         plan.Oa_rel)
+            with span("reduce"):
+                mk = F.max(axis=0)
+                out[sel[okc]] = mk[okc]
             ok[c0:c0 + chunk] = okc
         if not ok[0] and mk0 is not None:
             # the master's own schedule always certifies; if the check
@@ -941,13 +952,14 @@ def simulate_batch(g: EDag, alphas, m: int = 4, unit: float = 1.0,
     ``simulate_reference_classes`` — the class engine verifies the
     recorded issue order *and* the recorded slot provenance per point.
     """
-    g._finalize()
-    pol = ExecPolicy.resolve(backend=backend, replay_dtype=replay_dtype,
-                             mem_budget=mem_budget, use_cache=use_cache,
-                             policy=policy)
-    spec = SweepSpec.make(alphas, ms=(m,), compute_slots=(compute_slots,),
-                          unit=unit)
-    return _batch_for_pair(g, spec, spec.ms[0], spec.css[0], pol)
+    with span("query", entry="simulate_batch"):
+        g._finalize()
+        pol = ExecPolicy.resolve(backend=backend, replay_dtype=replay_dtype,
+                                 mem_budget=mem_budget, use_cache=use_cache,
+                                 policy=policy)
+        spec = SweepSpec.make(alphas, ms=(m,),
+                              compute_slots=(compute_slots,), unit=unit)
+        return _batch_for_pair(g, spec, spec.ms[0], spec.css[0], pol)
 
 
 def latency_sweep(g: EDag, alphas, m: int = 4, unit: float = 1.0,
@@ -1035,9 +1047,10 @@ def sweep_grid(g: EDag, alphas, ms=(4,), compute_slots=(0,),
     m × compute_slots grid (one class-mode recording per (m, slots)
     pair); the first output axis then indexes the P class vectors.
     """
-    pol = ExecPolicy.resolve(backend=backend, replay_dtype=replay_dtype,
-                             mem_budget=mem_budget, use_cache=use_cache,
-                             policy=policy)
-    spec = SweepSpec.make(alphas, ms=ms, compute_slots=compute_slots,
-                          unit=unit)
-    return _sweep_grid_spec(g, spec, pol)
+    with span("query", entry="sweep_grid"):
+        pol = ExecPolicy.resolve(backend=backend, replay_dtype=replay_dtype,
+                                 mem_budget=mem_budget, use_cache=use_cache,
+                                 policy=policy)
+        spec = SweepSpec.make(alphas, ms=ms, compute_slots=compute_slots,
+                              unit=unit)
+        return _sweep_grid_spec(g, spec, pol)

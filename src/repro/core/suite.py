@@ -78,6 +78,7 @@ import numpy as np
 
 from . import backend as _bk
 from . import schedule_cache as _sc
+from .counters import span
 from .graph import EDag, _auto_sweep_chunk, concat_edags
 from .plan import ExecPolicy, SweepSpec
 from .scheduler import (_ReplayPlan, _aug_level_valid,
@@ -207,15 +208,18 @@ def suite_t_inf_sweep(suite: EDagSuite, alphas, unit: float = 1.0,
     lv = u._level_csr()
     out = []
     for i in range(0, len(alphas), chunk):
-        if cls is not None:
-            F = np.where(u.is_mem[:, None], alphas[i:i + chunk].T[cls],
-                         float(unit))
-        else:
-            F = np.where(u.is_mem[:, None], alphas[None, i:i + chunk],
-                         float(unit))
-        pol.accumulate(lv, F, _bk.column_quanta(alphas[i:i + chunk], unit),
-                       clamp=True)
-        out.append(_bk.segment_max_rows(F, suite.offsets))
+        with span("fill", rows=u.n_vertices,
+                  columns=len(alphas[i:i + chunk])):
+            if cls is not None:
+                F = np.where(u.is_mem[:, None], alphas[i:i + chunk].T[cls],
+                             float(unit))
+            else:
+                F = np.where(u.is_mem[:, None], alphas[None, i:i + chunk],
+                             float(unit))
+            quanta = _bk.column_quanta(alphas[i:i + chunk], unit)
+        pol.accumulate(lv, F, quanta, clamp=True)
+        with span("reduce"):
+            out.append(_bk.segment_max_rows(F, suite.offsets))
     return np.concatenate(out, axis=1)
 
 
@@ -289,16 +293,17 @@ class _SuitePlan:
         on a class-mode plan, (k, n_classes) class-vector rows."""
         pol = ExecPolicy.resolve(policy=pol)
         k = len(alphas)
-        F = np.empty((self.n + 1, k))
-        F.fill(unit)
-        if self.cls_mem is not None:
-            F[self.mem_rows] = alphas.T[self.cls_mem]
-        else:
-            F[self.mem_rows] = alphas        # rows of memory vertices
-        F[-1] = 0.0
-        R = np.zeros_like(F)
-        pol.accumulate(self.lv, F, _bk.column_quanta(alphas, unit),
-                       clamp=False, R_out=R)
+        with span("fill", rows=self.n + 1, columns=k):
+            F = np.empty((self.n + 1, k))
+            F.fill(unit)
+            if self.cls_mem is not None:
+                F[self.mem_rows] = alphas.T[self.cls_mem]
+            else:
+                F[self.mem_rows] = alphas    # rows of memory vertices
+            F[-1] = 0.0
+            R = np.zeros_like(F)
+            quanta = _bk.column_quanta(alphas, unit)
+        pol.accumulate(self.lv, F, quanta, clamp=False, R_out=R)
         return F, R
 
 
@@ -317,17 +322,20 @@ def _member_schedule(g: EDag, m: int, cs: int, unit: float, a0: float,
             _sc.stats.add("memory_hits")
             return p.topo, p.O_mem, p.O_alu, p.level_aug, False
         if n >= _sc.min_vertices():
-            got = _sc.load(g.trace_digest(), m, cs, n, unit)
-            if got is not None:
+            with span("schedule.load") as sp:
+                got = _sc.load(g.trace_digest(), m, cs, n, unit)
+                hit = got is not None and _validate_schedule(
+                    g, m, cs, *got[:3]) is not None
+                sp.set_metadata(hit=int(hit))
+            if hit:
+                _sc.stats.add("disk_hits")
                 topo, O_mem, O_alu, level = got
-                if _validate_schedule(g, m, cs, topo, O_mem,
-                                      O_alu) is not None:
-                    _sc.stats.add("disk_hits")
-                    return topo, O_mem, O_alu, level, False
+                return topo, O_mem, O_alu, level, False
         _sc.stats.add("misses")
     _sc.stats.add("record_runs")
-    _, topo, O_mem, O_alu = _event_loop(g.is_mem, g._sim_lists(), m, a0,
-                                        unit, cs, record=True)
+    with span("schedule.record", vertices=n):
+        _, topo, O_mem, O_alu = _event_loop(g.is_mem, g._sim_lists(), m,
+                                            a0, unit, cs, record=True)
     return topo, O_mem, O_alu, None, True
 
 
@@ -350,8 +358,9 @@ def _member_schedule_classes(g: EDag, m: int, cs: int, unit: float,
             return p.topo, p.O_mem, p.O_alu, p.prov, p.level_aug, False
         _sc.stats.add("misses")
     _sc.stats.add("record_runs")
-    _, topo, O_mem, O_alu, prov = _event_loop_classes(
-        g.is_mem, g._sim_lists(), m, a0, cls, unit, cs, record=True)
+    with span("schedule.record", vertices=g.n_vertices):
+        _, topo, O_mem, O_alu, prov = _event_loop_classes(
+            g.is_mem, g._sim_lists(), m, a0, cls, unit, cs, record=True)
     return topo, O_mem, O_alu, prov, None, True
 
 
@@ -529,35 +538,39 @@ def _group_grid_batch(suite: EDagSuite, member_idx, out: np.ndarray,
                      for k in member_idx) if classes else None)
     key = (tuple(member_idx), tuple(pairs), float(unit), cls_key)
     plan = suite._suite_plans.get(key) if pol.use_cache else None
-    if plan is not None:
-        suite._suite_plans.move_to_end(key)
-    else:
-        a0 = alphas[0] if classes else float(alphas[0])
-        plan = _build_suite_plan(
-            suite, pairs, unit, a0, pol.use_cache, member_idx=member_idx,
-            n_classes=alphas.shape[1] if classes else None)
-        if pol.use_cache:
-            _memo_suite_plan(suite, key, plan)
+    with span("plan", hit=int(plan is not None)):
+        if plan is not None:
+            suite._suite_plans.move_to_end(key)
+        else:
+            a0 = alphas[0] if classes else float(alphas[0])
+            plan = _build_suite_plan(
+                suite, pairs, unit, a0, pol.use_cache,
+                member_idx=member_idx,
+                n_classes=alphas.shape[1] if classes else None)
+            if pol.use_cache:
+                _memo_suite_plan(suite, key, plan)
     B = len(plan.blocks)
     ok = np.zeros((B, P), dtype=bool)
     chunk = pol.points_chunk(plan.n, P)
     for c0 in range(0, P, chunk):
         cols = np.arange(c0, min(c0 + chunk, P))
         F, R = plan.replay(alphas[cols], unit, pol=pol)
-        mk = _bk.segment_max_rows(F[:-1], plan.seg_ptr)
+        with span("reduce"):
+            mk = _bk.segment_max_rows(F[:-1], plan.seg_ptr)
         for b, blk in enumerate(plan.blocks):
             if blk is None:           # empty member: makespan 0 everywhere
                 ok[b, cols] = True
                 continue
             off, n = blk.off, blk.g.n_vertices
             Fv, Rv = F[off:off + n], R[off:off + n]
-            okc = _verify_class(blk.g, blk.rank, Fv, Rv,
-                                blk.O_mem, blk.Om_rel)
-            if blk.prov is not None:
-                okc &= _verify_slots(blk, Fv)
-            if blk.cs:
-                okc &= _verify_class(blk.g, blk.rank, Fv, Rv,
-                                     blk.O_alu, blk.Oa_rel)
+            with span("verify"):
+                okc = _verify_class(blk.g, blk.rank, Fv, Rv,
+                                    blk.O_mem, blk.Om_rel)
+                if blk.prov is not None:
+                    okc &= _verify_slots(blk, Fv)
+                if blk.cs:
+                    okc &= _verify_class(blk.g, blk.rank, Fv, Rv,
+                                         blk.O_alu, blk.Oa_rel)
             out[blk.trace, cols[okc], blk.pair] = mk[b, okc]
             ok[b, cols] = okc
     if not ok.all():
@@ -611,8 +624,9 @@ def _suite_sweep_grid_spec(suite: EDagSuite, spec: SweepSpec,
         sub = _suite_grid_batch(suite, spec.uniq,
                                 [pairs[i] for i in idxs], spec.unit, pol)
         res[:, :, idxs] = sub
-    out[:] = spec.restore(res, axis=1).reshape(
-        K, spec.n_points, len(spec.ms), len(spec.css))
+    with span("reduce"):
+        out[:] = spec.restore(res, axis=1).reshape(
+            K, spec.n_points, len(spec.ms), len(spec.css))
     return out
 
 
@@ -658,12 +672,13 @@ def suite_sweep_grid(suite: EDagSuite, alphas, ms=(4,), compute_slots=(0,),
     issue-order check plus the per-block ``_verify_slots`` provenance
     check — one stacked level pass per distinct m, exactly like scalar
     grids, bit-identical to ``simulate_reference_classes``."""
-    pol = ExecPolicy.resolve(backend=backend, replay_dtype=replay_dtype,
-                             mem_budget=mem_budget, use_cache=use_cache,
-                             policy=policy)
-    spec = SweepSpec.make(alphas, ms=ms, compute_slots=compute_slots,
-                          unit=unit)
-    return _suite_sweep_grid_spec(suite, spec, pol)
+    with span("query", entry="suite_sweep_grid"):
+        pol = ExecPolicy.resolve(backend=backend, replay_dtype=replay_dtype,
+                                 mem_budget=mem_budget, use_cache=use_cache,
+                                 policy=policy)
+        spec = SweepSpec.make(alphas, ms=ms, compute_slots=compute_slots,
+                              unit=unit)
+        return _suite_sweep_grid_spec(suite, spec, pol)
 
 
 def suite_latency_sweep(suite: EDagSuite, alphas, m: int = 4,
