@@ -51,6 +51,7 @@ after it (uncertified float32 columns), and each is counted in ``stats``.
 """
 from __future__ import annotations
 
+import functools
 import os
 from collections import OrderedDict
 from dataclasses import dataclass
@@ -71,7 +72,11 @@ _REPLAY_DTYPES = ("float32", "float64")
 #: those the numpy kernel handled end to end (including chunks whose f32
 #: pass certified no column at all); ``certified_columns`` /
 #: ``demoted_columns`` count sweep columns the float32 certificate
-#: accepted / demoted to the float64 numpy kernel.  ``numpy_f64_passes``
+#: accepted / demoted to the float64 numpy kernel.  ``f32_whole_chunks``
+#: counts the float32 chunks that took the whole-chunk path: every
+#: column passed the pre-screen and certified, so the device result cast
+#: into ``F`` / ``R_out`` stands whole (nothing saved, nothing demoted).
+#: ``numpy_f64_passes``
 #: counts plain ``level_accumulate`` passes the jax backend routed to the
 #: numpy kernel because the device cannot run their float64 input
 #: exactly (no x64 flag, or a TPU).  Thread-safe: the
@@ -79,7 +84,8 @@ _REPLAY_DTYPES = ("float32", "float64")
 #: would skew the very counters its benchmarks and fault-injection gates
 #: assert on.
 stats = Stats(chunks=0, jax_chunks=0, jax_f64_chunks=0, numpy_chunks=0,
-              certified_columns=0, demoted_columns=0, numpy_f64_passes=0)
+              certified_columns=0, demoted_columns=0, f32_whole_chunks=0,
+              numpy_f64_passes=0)
 
 #: Fault-injection hook (``serve.faults``): when set, called with no
 #: arguments at the top of the jax kernel path.  An exception it raises
@@ -639,7 +645,8 @@ class DeviceReplayError(RuntimeError):
 
 
 def _accumulate_jax(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
-                    R_out: Optional[np.ndarray] = None) -> np.ndarray:
+                    R_out: Optional[np.ndarray] = None,
+                    land=None) -> np.ndarray:
     """jax backend: jit-compiled level loop + pallas inner step.
 
     Computes the same (max,+) recurrence as the numpy kernel in the input
@@ -652,6 +659,12 @@ def _accumulate_jax(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
     order-verification pass) the whole recurrence, finish times *and*
     ready times, runs on the accelerator in one fused level loop with no
     numpy round-trip.  Any device failure raises ``DeviceReplayError``.
+
+    ``land``, when given, says where the result goes before any of it
+    comes back: it is called with the per-column ``max(|F|)`` of the
+    result, reduced on the device (``_column_absmax``), and returns the
+    ``(F, R_out)`` pair to cast the result into.  Returns the matrix the
+    finish times landed in.
     """
     import jax
     import jax.numpy as jnp
@@ -689,16 +702,19 @@ def _accumulate_jax(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
                 (1, F.shape[1]), dtype=F.dtype)
             args = (jnp.asarray(F), Rin, jnp.asarray(gather),
                     jnp.asarray(dsts), jnp.asarray(qp))
-        # the call returns once the loop is dispatched; the download
-        # below waits for the device to finish it
+        # the call returns once the loop is dispatched; the first read
+        # of a result (the column maxima, else the download) waits for
+        # the device to finish it
         with span("replay.run", levels=max(L - 1, 0), rows=Rmax,
                   width=Dmax, edges=len(lv.esrc),
                   slots=max(L - 1, 0) * Rmax * Dmax):
             Fj, Rj = fn(*args)
+            colmax = None if land is None else _column_absmax(Fj)
+        dst = land(colmax) if land is not None else (F, R_out)
         with span("replay.download", bytes=moved):
-            F[:] = np.asarray(Fj)
+            dst[0][:] = np.asarray(Fj)
             if want_r:
-                R_out[:] = np.asarray(Rj)
+                dst[1][:] = np.asarray(Rj)
     except Exception as exc:
         raise DeviceReplayError(
             f"device level pass failed on {jax.default_backend()} for plan "
@@ -706,7 +722,29 @@ def _accumulate_jax(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
             f"k={F.shape[1]}, dtype={F.dtype}, has_q={has_q}, "
             f"clamp={clamp}, want_r={want_r}: {type(exc).__name__}: "
             f"{exc}") from exc
-    return F
+    return dst[0]
+
+
+@functools.cache
+def _absmax_program():
+    """The jitted per-column ``max(|x|)`` of ``_column_absmax``: a program
+    of its own, apart from the level loop's ``jit_run``."""
+    import jax
+    import jax.numpy as jnp
+
+    def column_absmax(x):
+        a = jnp.abs(x)
+        # NaN reads as inf, so a column holding one never certifies;
+        # initial 0 gives an empty matrix a maximum
+        return jnp.max(jnp.where(jnp.isnan(a), jnp.inf, a), axis=0,
+                       initial=0.0)
+    return jax.jit(column_absmax)
+
+
+def _column_absmax(Fj) -> np.ndarray:
+    """Per-column ``max(|F|)`` of a device matrix, reduced on the device:
+    k values come back (as float64, exactly), not the matrix."""
+    return np.asarray(_absmax_program()(Fj), dtype=np.float64)
 
 
 # ------------------------------------------------------------------ dispatch
@@ -805,9 +843,11 @@ def column_quanta(alphas, unit: float) -> np.ndarray:
     return np.minimum(q, float(_lsb_quantum(float(unit))))
 
 
-def _certified_f32(F32: np.ndarray, quanta: np.ndarray,
+def _certified_f32(M32: np.ndarray, quanta: np.ndarray,
                    n_levels: int) -> np.ndarray:
-    """Columns of a float32 level pass that are provably exact.
+    """Columns of a float32 level pass that are provably exact, from
+    ``M32``, the per-column ``max(|F32|)`` of the pass's finish matrix
+    (``_column_absmax``, reduced on the device).
 
     Exactness argument: all true values of a column are nonnegative
     integer multiples of its quantum ``q`` (max is exact; every add sums
@@ -830,8 +870,6 @@ def _certified_f32(F32: np.ndarray, quanta: np.ndarray,
     non-representable inputs can never certify; the quantum floor keeps
     certified values clear of float32 subnormals (flushed to zero on
     some accelerators)."""
-    M32 = (np.abs(F32).max(axis=0).astype(np.float64) if len(F32)
-           else np.zeros(F32.shape[1]))
     thr = _f32_thresholds(quanta, n_levels)
     return np.isfinite(M32) & (M32 < thr)
 
@@ -886,15 +924,18 @@ def replay_accumulate(lv: LevelCSR, F: np.ndarray, quanta: np.ndarray,
         raise ValueError("quanta must have one entry per column")
     stats.add("chunks")
     with span("replay", levels=max(lv.n_levels - 1, 0), rows=F.shape[0],
-              columns=F.shape[1]):
-        return _replay(lv, F, quanta, clamp, R_out, backend, replay_dtype)
+              columns=F.shape[1]) as sp:
+        sp.set_metadata(whole=int(_replay(lv, F, quanta, clamp, R_out,
+                                          backend, replay_dtype)))
+    return F
 
 
 def _replay(lv: LevelCSR, F: np.ndarray, quanta: np.ndarray, clamp: bool,
             R_out: Optional[np.ndarray], backend: Optional[str],
-            replay_dtype: Optional[str]) -> np.ndarray:
+            replay_dtype: Optional[str]) -> bool:
     """``replay_accumulate`` past its argument checks; each host stage of
-    the float32 mode is a span of its own (``replay.*``)."""
+    the float32 mode is a span of its own (``replay.*``).  Returns whether
+    the chunk took the float32 whole-chunk path."""
     b = select_backend(backend)
     # an explicit replay_dtype argument is validated on every backend (a
     # typo'd argument is a caller bug and must not surface only once the
@@ -904,7 +945,8 @@ def _replay(lv: LevelCSR, F: np.ndarray, quanta: np.ndarray, clamp: bool,
            if (b == "jax" or replay_dtype) else "float64")
     if b != "jax" or F.shape[1] == 0:
         stats.add("numpy_chunks")
-        return _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
+        _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
+        return False
     import jax
     if pol == "float64":
         if on_tpu():
@@ -920,65 +962,100 @@ def _replay(lv: LevelCSR, F: np.ndarray, quanta: np.ndarray, clamp: bool,
         _accumulate_jax(lv, F, clamp=clamp, R_out=R_out)
         stats.add("jax_chunks")
         stats.add("jax_f64_chunks")
-        return F
-    # error-bounded float32 mode.  Pre-screen: only columns whose base
-    # costs all sit strictly below the threshold go to the device.  This
-    # is load-bearing for soundness, not just a fast path — the
-    # a-posteriori certificate only detects rounding *inside* the pass,
-    # so the initial float32 cast of the bases must be lossless, which
-    # |base| < thr <= 2^24 * q guarantees (such a base is a multiple of
-    # q with fewer than 25 significand bits).  A base at or past the
-    # threshold could cast lossily and then cancel below the observed
-    # max|F32| (clamped sweeps admit negative bases), so such columns
-    # always take the float64 numpy kernel.  For the monotone replay
-    # (clamp off, nonneg bases) a base past the threshold also forces
-    # the makespan past it, so nothing certifiable is ever screened off;
-    # for clamped sweeps the screen is merely conservative.
+        return False
+    return _replay_f32(lv, F, quanta, clamp, R_out)
+
+
+#: Rows folded into one reduction row by ``_screen_cast``: a C-order
+#: (rows, k) matrix viewed as (rows / 32, 32 k) reduces along long
+#: contiguous rows, where k-wide rows cost a ufunc call each.
+_SCREEN_FOLD = 32
+
+
+def _screen_cast(F: np.ndarray):
+    """``F``'s float32 cast and its per-column ``max(|F|)`` (0 for no
+    rows), as ``max(colmax, -colmin)`` in float64, exact and with no
+    ``|F|`` temporary.  NaN propagates into the maximum.  A value past
+    float32's range casts to inf quietly: its column fails the screen,
+    and its cast is never used."""
+    n, k = F.shape
+    with np.errstate(over="ignore"):
+        F32 = F.astype(np.float32)
+    m = n - n % _SCREEN_FOLD
+    head, tail = F[:m].reshape(-1, _SCREEN_FOLD * k), F[m:]
+    hi = np.maximum(head.max(axis=0, initial=0.0).reshape(-1, k).max(axis=0),
+                    tail.max(axis=0, initial=0.0))
+    lo = np.minimum(head.min(axis=0, initial=0.0).reshape(-1, k).min(axis=0),
+                    tail.min(axis=0, initial=0.0))
+    return F32, np.maximum(hi, -lo)
+
+
+def _replay_f32(lv: LevelCSR, F: np.ndarray, quanta: np.ndarray,
+                clamp: bool, R_out: Optional[np.ndarray]) -> bool:
+    """The error-bounded float32 mode of ``replay_accumulate``.
+
+    No host matrix is sliced or staged on the way back: ``F`` is screened
+    and cast (``_screen_cast``), ``R_out`` cast, and the whole device
+    result is cast straight into ``F`` / ``R_out``.  Columns are
+    independent in the recurrence, so a column that fails the pre-screen
+    or the certificate rides along and is then replayed by the float64
+    numpy kernel from its bases, saved before the result lands.  Returns
+    whether every column was kept (the whole-chunk path)."""
+    k = F.shape[1]
+    # Pre-screen: only columns whose base costs all sit strictly below
+    # the threshold may keep their device result.  This is load-bearing
+    # for soundness, not just a fast path — the a-posteriori certificate
+    # only detects rounding *inside* the pass, so the initial float32
+    # cast of the bases must be lossless, which |base| < thr <= 2^24 * q
+    # guarantees (such a base is a multiple of q with fewer than 25
+    # significand bits).  A base at or past the threshold could cast
+    # lossily and then cancel below the observed max|F32| (clamped sweeps
+    # admit negative bases), so such columns always take the float64
+    # numpy kernel.  For the monotone replay (clamp off, nonneg bases) a
+    # base past the threshold also forces the makespan past it, so
+    # nothing certifiable is ever screened off; for clamped sweeps the
+    # screen is merely conservative.  F's cast is timed with it.
     with span("replay.prescreen"):
         thr = _f32_thresholds(quanta, lv.n_levels)
-        base_mag = (np.abs(F).max(axis=0) if len(F)
-                    else np.zeros(F.shape[1]))
-        live_idx = np.flatnonzero(base_mag < thr)
-    if len(live_idx) == 0:
+        F32, base_mag = _screen_cast(F)
+        live = base_mag < thr
+    if not live.any():
         stats.add("numpy_chunks")
-        stats.add("demoted_columns", F.shape[1])
-        with span("replay.demote", columns=F.shape[1]):
-            return _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
-    with span("replay.cast"):
-        F32 = F[:, live_idx].astype(np.float32)
-        R32 = (R_out[:, live_idx].astype(np.float32) if R_out is not None
-               else None)
-    _accumulate_jax(lv, F32, clamp=clamp, R_out=R32)
-    with span("replay.certify"):
-        okl = _certified_f32(F32, quanta[live_idx], lv.n_levels)
-    ok = np.zeros(F.shape[1], dtype=bool)
-    ok[live_idx[okl]] = True
-    n_ok = int(okl.sum())
+        stats.add("demoted_columns", k)
+        with span("replay.demote", columns=k):
+            _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
+        return False
+    with span("replay.cast"), np.errstate(over="ignore"):
+        R32 = R_out.astype(np.float32) if R_out is not None else None
+    ok = bad = Fb = Rb = None
+
+    def land(colmax):
+        nonlocal ok, bad, Fb, Rb
+        with span("replay.certify"):
+            ok = live & _certified_f32(colmax, quanta, lv.n_levels)
+        bad = ~ok
+        if bad.any():
+            with span("replay.merge"):
+                Fb = np.ascontiguousarray(F[:, bad])
+                Rb = (np.ascontiguousarray(R_out[:, bad])
+                      if R_out is not None else None)
+        # certified columns are exact multiples of q below 2^24 * q — the
+        # float32 values ARE the float64 values, the cast is lossless
+        return F, R_out
+
+    _accumulate_jax(lv, F32, clamp=clamp, R_out=R32, land=land)
+    n_ok = int(ok.sum())
     stats.add("certified_columns", n_ok)
-    if n_ok == 0:
-        # nothing certified: F still holds the untouched base costs, so
-        # the numpy kernel runs in place — no slice copies needed
-        stats.add("numpy_chunks")
-        stats.add("demoted_columns", F.shape[1])
-        with span("replay.demote", columns=F.shape[1]):
-            return _accumulate_numpy(lv, F, clamp=clamp, R_out=R_out)
-    # certified columns are exact multiples of q below 2^24 * q — the
-    # float32 values ARE the float64 values, the cast is lossless
-    with span("replay.merge"):
-        F[:, ok] = F32[:, okl]
+    if n_ok == k:
+        stats.add("jax_chunks")
+        stats.add("f32_whole_chunks")
+        return True
+    # a chunk with no certified column counts as a numpy chunk
+    stats.add("jax_chunks" if n_ok else "numpy_chunks")
+    stats.add("demoted_columns", k - n_ok)
+    with span("replay.demote", columns=k - n_ok):
+        _accumulate_numpy(lv, Fb, clamp=clamp, R_out=Rb)
+        F[:, bad] = Fb
         if R_out is not None:
-            R_out[:, ok] = R32[:, okl]
-    stats.add("jax_chunks")
-    bad = ~ok
-    n_bad = F.shape[1] - n_ok
-    if n_bad:
-        stats.add("demoted_columns", n_bad)
-        with span("replay.demote", columns=n_bad):
-            Fb = np.ascontiguousarray(F[:, bad])
-            Rb = (np.ascontiguousarray(R_out[:, bad]) if R_out is not None
-                  else None)
-            _accumulate_numpy(lv, Fb, clamp=clamp, R_out=Rb)
-            F[:, bad] = Fb
-            if R_out is not None:
-                R_out[:, bad] = Rb
-    return F
+            R_out[:, bad] = Rb
+    return False

@@ -484,3 +484,158 @@ def test_jax_jit_cache_is_bounded_lru(monkeypatch, x64_off):
                              backend="jax")
             assert len(bk._JAX_CACHE) <= 2
     bk._JAX_CACHE.clear()
+
+
+# ------------------------------------------------- float32 whole-chunk path
+
+def _bases(g, alphas, unit=1.0, sentinel=False):
+    """Base-cost matrix of a sweep: ``alpha`` on memory rows, ``unit``
+    elsewhere, one column per alpha (plus a zero sentinel row)."""
+    mem = np.asarray(g.is_mem, dtype=bool)
+    F = np.where(mem[:, None], np.asarray(alphas, dtype=np.float64)[None],
+                 unit)
+    if sentinel:
+        F = np.vstack([F, np.zeros((1, len(alphas)))])
+    return np.ascontiguousarray(F)
+
+
+@pytest.mark.parametrize("n", [90, 96, 20])
+@pytest.mark.parametrize("clamp,alphas,with_r", [
+    (True, [50.0, -3.0, 125.0, -50.0, 300.0], False),
+    (False, [50.0, 75.0, 125.0, 200.0, 300.0], True),
+])
+def test_f32_whole_chunk_bit_identical(x64_off, n, clamp, alphas, with_r):
+    """Every column live and certified: the device result lands straight
+    in F (and R_out) and equals the float64 numpy kernel bit for bit —
+    a clamped sweep with negative bases and no R_out, and an unclamped
+    replay with R_out; with rows the screen's 32-row fold takes whole,
+    with a ragged tail, and with the tail alone."""
+    g = _random_edag(47, n=n)
+    lv = g._level_csr()
+    F = _bases(g, alphas)
+    q = column_quanta(alphas, 1.0)
+    R = np.zeros_like(F) if with_r else None
+    Rw = np.zeros_like(F) if with_r else None
+    want = replay_accumulate(lv, F.copy(), q, clamp=clamp, R_out=Rw,
+                             backend="numpy")
+    bk.reset_stats()
+    got = replay_accumulate(lv, F, q, clamp=clamp, R_out=R, backend="jax")
+    assert got is F
+    assert np.array_equal(got, want)
+    if with_r:
+        assert np.array_equal(R, Rw) and R.any()
+    assert bk.stats["f32_whole_chunks"] == bk.stats["jax_chunks"] == 1
+    assert bk.stats["certified_columns"] == len(alphas)
+    assert bk.stats["demoted_columns"] == bk.stats["numpy_chunks"] == 0
+
+
+def test_f32_chunk_with_screened_and_uncertified_columns(x64_off):
+    """One chunk, one column past the pre-screen (its base is 2^24 + 1)
+    and one past the certificate (base 2^23 + 1, makespan past 2^24),
+    beside clean ones: bit-identical to the float64 kernel, F and R_out,
+    and not counted as a whole chunk."""
+    g = EDag()
+    for i in range(12):
+        g.add_vertex(is_mem=True)
+        if i:
+            g.add_edge(i - 1, i)
+    g._finalize()
+    lv = g._level_csr()
+    alphas = [50.0, 2.0 ** 24 + 1, 2.0 ** 23 + 1, 300.0]
+    q = column_quanta(alphas, 1.0)
+    thr = bk._f32_thresholds(q, lv.n_levels)
+    assert alphas[1] >= thr[1] and alphas[2] < thr[2]
+    F = _bases(g, alphas)
+    R, Rw = np.zeros_like(F), np.zeros_like(F)
+    want = replay_accumulate(lv, F.copy(), q, R_out=Rw, backend="numpy")
+    bk.reset_stats()
+    got = replay_accumulate(lv, F, q, R_out=R, backend="jax")
+    assert np.array_equal(got, want) and np.array_equal(R, Rw)
+    assert bk.stats["f32_whole_chunks"] == 0
+    assert bk.stats["jax_chunks"] == bk.stats["chunks"] == 1
+    assert bk.stats["certified_columns"] == 2
+    assert bk.stats["demoted_columns"] == 2
+
+
+def test_f32_overflow_to_inf_does_not_certify(x64_off, monkeypatch):
+    """Bases of 2^127 with a quantum of 2^127 pass the pre-screen (the
+    bound reads 2^151) and cast exactly, but two in a row overflow
+    float32 to inf: the certificate gets inf as the column's device
+    maximum and the column demotes.  The column at 2^100 certifies."""
+    g = EDag()
+    for i in range(3):
+        g.add_vertex(is_mem=True)
+        if i:
+            g.add_edge(i - 1, i)
+    g._finalize()
+    lv = g._level_csr()
+    big, fine = 2.0 ** 127, 2.0 ** 100
+    F = _bases(g, [big, fine])
+    q = np.array([big, fine])
+    want = replay_accumulate(lv, F.copy(), q, backend="numpy")
+    assert want[-1, 0] == 3 * big > float(np.finfo(np.float32).max)
+    seen = []
+    real = bk._certified_f32
+
+    def spy(M32, quanta, n_levels):
+        seen.append(M32.copy())
+        return real(M32, quanta, n_levels)
+    monkeypatch.setattr(bk, "_certified_f32", spy)
+    bk.reset_stats()
+    got = replay_accumulate(lv, F, q, backend="jax")
+    assert np.array_equal(got, want)
+    assert len(seen) == 1
+    assert np.isinf(seen[0][0]) and seen[0][1] == 3 * fine
+    assert bk.stats["certified_columns"] == 1
+    assert bk.stats["demoted_columns"] == 1
+    assert bk.stats["f32_whole_chunks"] == 0
+
+
+def test_column_absmax_reads_nan_as_inf(x64_off):
+    """The device certificate's reduction: max(|x|) per column, a NaN
+    reads as inf (never certifies), an empty matrix reads 0."""
+    x = np.array([[1.0, -7.0, np.nan], [-3.0, 2.0, 0.0]], dtype=np.float32)
+    got = bk._column_absmax(jax.numpy.asarray(x))
+    assert got.dtype == np.float64
+    assert np.array_equal(got, [3.0, 7.0, np.inf])
+    empty = jax.numpy.zeros((0, 2), dtype=jax.numpy.float32)
+    assert np.array_equal(bk._column_absmax(empty), [0.0, 0.0])
+
+
+@pytest.mark.parametrize("n", [0, 1, 31, 32, 8192 * 2 + 45])
+def test_screen_cast_matches_abs_max_and_astype(n):
+    """One blocked pass gives today's two answers: the float32 cast and
+    the per-column max(|F|), NaN and infinities included."""
+    rng = np.random.default_rng(n)
+    F = rng.integers(-2 ** 30, 2 ** 30, size=(n, 5)).astype(np.float64)
+    if n > 1:
+        F[n // 2, 1] = np.nan
+        F[n - 1, 3] = -np.inf
+        F[0, 4] = 1e300                 # past float32: casts to inf
+    F32, mag = bk._screen_cast(F)
+    with np.errstate(over="ignore"):
+        cast = F.astype(np.float32)
+    assert F32.dtype == np.float32
+    assert np.array_equal(F32, cast, equal_nan=True)
+    want = np.abs(F).max(axis=0) if n else np.zeros(5)
+    assert np.array_equal(mag, want, equal_nan=True)
+
+
+def test_f32_whole_chunks_cover_a_clean_suite_grid(x64_off):
+    """A clean union suite_sweep_grid takes the whole-chunk path in every
+    chunk and matches the numpy grid bit for bit."""
+    from repro.apps.polybench import trace_kernel
+    from repro.core import EDagSuite, suite_sweep_grid
+
+    suite = EDagSuite([trace_kernel("atax", 4), trace_kernel("mvt", 4),
+                       trace_kernel("trisolv", 5)])
+    want = suite_sweep_grid(suite, CLEAN_ALPHAS, ms=(2, 4),
+                            compute_slots=(0, 3), backend="numpy",
+                            use_cache=False)
+    bk.reset_stats()
+    got = suite_sweep_grid(suite, CLEAN_ALPHAS, ms=(2, 4),
+                           compute_slots=(0, 3), backend="jax",
+                           use_cache=False)
+    assert np.array_equal(got, want)
+    assert bk.stats["f32_whole_chunks"] == bk.stats["chunks"] > 0
+    assert bk.stats["demoted_columns"] == bk.stats["numpy_chunks"] == 0

@@ -82,8 +82,8 @@ def profiled(tmp_path_factory):
     passes = []
     real = bk._accumulate_jax
 
-    def spy(lv, F, clamp=True, R_out=None):
-        res = real(lv, F, clamp=clamp, R_out=R_out)
+    def spy(lv, F, clamp=True, R_out=None, land=None):
+        res = real(lv, F, clamp=clamp, R_out=R_out, land=land)
         gather, dsts = bk._jax_padded(lv)
         qp = np.asarray(lv.qpred if lv.qpred is not None
                         else np.zeros(1, dtype=np.int32), dtype=np.int32)
@@ -164,6 +164,20 @@ def test_demote_span_counts_the_demoted_columns(profiled):
     demoted = [s["stats"]["columns"] for s in spans
                if s["name"] == "replay.demote"]
     assert demoted and all(c == 1 for c in demoted)
+
+
+def test_replay_span_says_whether_the_chunk_was_whole(profiled):
+    spans, _, _ = profiled
+    replays = [s for s in spans if s["name"] == "replay"]
+    # the reports' 2^23 + 1 column fails the certificate; the suite's
+    # clean alphas take the whole-chunk path
+    assert {s["stats"]["whole"] for s in replays} == {0, 1}
+    for r in replays:
+        inside = {s["name"] for s in spans if r["s"] <= s["s"] < r["e"]}
+        if r["stats"]["whole"]:
+            assert not inside & {"replay.merge", "replay.demote"}
+        else:
+            assert {"replay.merge", "replay.demote"} <= inside
 
 
 def test_no_span_is_opened_per_level(profiled):

@@ -93,3 +93,15 @@ def test_level_loop_compiles_for_v5e(one_chip, compiled_for_chip, plan,
     compiled = jax.jit(run).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+@pytest.mark.parametrize("plan", PLANS, ids=[p[0] for p in PLANS])
+def test_column_absmax_compiles_for_v5e(one_chip, compiled_for_chip, plan):
+    """The float32 certificate's device reduction (``_column_absmax``)
+    over a plan's finish matrix: k values out, no temp the size of the
+    matrix."""
+    _, rows, _, _, _, k = plan
+    x = jax.ShapeDtypeStruct((rows + 1, k), jnp.float32, sharding=one_chip)
+    compiled = bk._absmax_program().lower(x).compile()
+    assert compiled.out_info.shape == (k,)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
