@@ -4,8 +4,8 @@ The paper traces HPCG 3.1's CG phase (setup excluded).  We implement the same
 computational core — SpMV over the 27-point stencil operator (diag 26,
 off-diag -1), dot products, and AXPYs — in both the scalar trace DSL and JAX.
 The paper's multigrid preconditioner is omitted (plain CG); this keeps the
-trace focused on the latency-relevant SpMV/dot pattern and is noted in
-DESIGN.md.
+trace focused on the latency-relevant SpMV/dot pattern; ROADMAP.md R2
+tracks adding it.
 """
 from __future__ import annotations
 

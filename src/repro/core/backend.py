@@ -79,13 +79,18 @@ _REPLAY_DTYPES = ("float32", "float64")
 #: ``numpy_f64_passes``
 #: counts plain ``level_accumulate`` passes the jax backend routed to the
 #: numpy kernel because the device cannot run their float64 input
-#: exactly (no x64 flag, or a TPU).  Thread-safe: the
+#: exactly (no x64 flag, or a TPU).  Each device level pass
+#: (``_accumulate_jax``) adds its predecessor edges to ``replay_edges``,
+#: the slots of its padded gather tensors (levels x rows x width, summed
+#: over the plan's segments) to ``replay_slots`` and its segment count to
+#: ``replay_segments``.  Thread-safe: the
 #: analysis service replays concurrent batches, and lost increments here
 #: would skew the very counters its benchmarks and fault-injection gates
 #: assert on.
 stats = Stats(chunks=0, jax_chunks=0, jax_f64_chunks=0, numpy_chunks=0,
               certified_columns=0, demoted_columns=0, f32_whole_chunks=0,
-              numpy_f64_passes=0)
+              numpy_f64_passes=0, replay_edges=0, replay_slots=0,
+              replay_segments=0)
 
 #: Fault-injection hook (``serve.faults``): when set, called with no
 #: arguments at the top of the jax kernel path.  An exception it raises
@@ -245,7 +250,7 @@ class LevelCSR:
     qonly_ptr: Optional[np.ndarray] = None
     qonly_dst: Optional[np.ndarray] = None
     seg_ptr: Optional[np.ndarray] = None    # block boundaries (union graphs)
-    jax_padded: Optional[tuple] = None      # memoized (gather, dsts) tensors
+    jax_padded: Optional[tuple] = None      # memoized padded segments
 
     def level_maxlens(self) -> list:
         if self.run_maxlen is None:
@@ -484,16 +489,14 @@ def _row_block(R: int, D: int, k: int) -> int:
 def _jax_padded(lv: LevelCSR):
     """Pad the per-level runs to rectangles for the jitted level loop.
 
-    Queue-only vertices (no DAG predecessor, just a slot chain) become
-    zero-width runs — their reduce sees only the folded-in qpred entry.
-    The padded tensors depend only on the partition, so they are memoized
-    on the LevelCSR (chunked sweeps call the kernel several times).
-
-    The row axis is padded to a multiple of 128, or of ``_ROW_TILE`` past
-    it, so the Pallas step's row blocks tile it exactly.  On a TPU a row
-    axis off the 128-lane width also makes XLA relayout the whole gather
-    tensor on every call (8 GB of device temp for the PAPER_15 m=8
-    union plan)."""
+    Returns the plan's segments, ``((gather, dsts), ...)``: levels 1..L-1
+    in order, cut into contiguous segments (``_band_segments``), each
+    padded to its own row width ``Rs`` as an (Ls, Rs, Dmax) gather tensor
+    and an (Ls, Rs) ``dsts``.  Queue-only vertices (no DAG predecessor,
+    just a slot chain) become zero-width runs -- their reduce sees only
+    the folded-in qpred entry.  The padded tensors depend only on the
+    partition, so they are memoized on the LevelCSR (chunked sweeps call
+    the kernel several times)."""
     if lv.jax_padded is not None:
         return lv.jax_padded
     with span("replay.pad", levels=max(lv.n_levels - 1, 0)):
@@ -501,29 +504,99 @@ def _jax_padded(lv: LevelCSR):
     return lv.jax_padded
 
 
+#: Narrowest padded row width: one 128-lane tile.
+_MIN_BAND = 128
+
+#: A segment joins its wider neighbour when widening it adds at most this
+#: many padded row-levels (its levels times the rows each gains).  On a
+#: TPU v5e (the level loop at k = 11 columns, Dmax = 2) one more segment
+#: cost 0.091 s of compiling, once per plan, and no device time that 20
+#: segments could show (under 2 us a call); one more padded row cost
+#: 0.078 us per level per call.  Widening pays where the rows it adds cost
+#: less, over 25 calls of the plan, than the compile it saves:
+#: 0.091 s / (25 x 0.078 us) = 46,000 row-levels.
+_SEGMENT_ROWS = 46_000
+
+
+def _band(width: np.ndarray) -> np.ndarray:
+    """Padded row width of levels ``width`` rows wide: the next power of
+    two, at least ``_MIN_BAND``; a multiple of 128, and of ``_ROW_TILE``
+    past it, so the Pallas step's row blocks tile it exactly.  On a TPU a
+    row axis off the 128-lane width also makes XLA relayout the whole
+    gather tensor on every call."""
+    w = np.asarray(width, dtype=np.int64)
+    # w - 1 < 2**e, so 2**e is the least power of two >= w
+    e = np.frexp(np.maximum(w - 1, 0).astype(np.float64))[1]
+    return np.maximum(_MIN_BAND, np.left_shift(1, e.astype(np.int64)))
+
+
+def _band_segments(width) -> list:
+    """Cut a sequence of levels into contiguous segments of one padded
+    width: ``[(start, stop, rows), ...]`` over ``width``'s indices, in
+    order.  Each level is rounded up to its band (``_band``); runs of
+    equal band are segments, and, narrowest band first, a segment whose
+    widening to its narrower wider neighbour adds at most
+    ``_SEGMENT_ROWS`` padded row-levels joins that neighbour."""
+    bands = _band(width)
+    if not len(bands):
+        return []
+    cut = np.flatnonzero(np.diff(bands)) + 1
+    segs = [[int(a), int(b), int(bands[a])] for a, b in
+            zip(np.concatenate(([0], cut)), np.concatenate((cut, [len(bands)])))]
+    for band in np.unique(bands):
+        for i, seg in enumerate(segs):
+            a, b, rows = seg
+            if rows != band:
+                continue
+            wider = [nb[2] for nb in segs[max(i - 1, 0):i] + segs[i + 1:i + 2]
+                     if nb[2] > rows]
+            if wider and (b - a) * (min(wider) - rows) <= _SEGMENT_ROWS:
+                seg[2] = min(wider)
+        merged = [segs[0]]
+        for seg in segs[1:]:
+            if seg[2] == merged[-1][2]:
+                merged[-1][1] = seg[1]
+            else:
+                merged.append(seg)
+        segs = merged
+    return [tuple(s) for s in segs]
+
+
 def _pad_levels(lv: LevelCSR):
     L = lv.n_levels
-    rcounts = np.diff(lv.run_ptr)
-    qcounts = (np.diff(lv.qonly_ptr) if lv.qonly_ptr is not None
-               else np.zeros(max(L, 1), dtype=np.int64))
-    Rmax = int((rcounts + qcounts[:len(rcounts)]).max()) if len(rcounts) \
-        else 0
-    Rmax = (_round_up(max(Rmax, 1), 128) if Rmax <= _ROW_TILE
-            else _round_up(Rmax, _ROW_TILE))
+    if L <= 1:
+        return ()
+    rcounts = np.diff(lv.run_ptr).astype(np.int64)
+    qptr = (lv.qonly_ptr.astype(np.int64) if lv.qonly_ptr is not None
+            else np.zeros(L + 1, dtype=np.int64))
+    qcounts = np.diff(qptr)
     Dmax = int(lv.run_lens.max()) if len(lv.run_lens) else 1
-    gather = np.full((L, Rmax, Dmax), -1, dtype=np.int32)
-    dsts = np.full((L, Rmax), -1, dtype=np.int32)
-    for lvl in range(1, L):
-        r0, r1 = lv.run_ptr[lvl], lv.run_ptr[lvl + 1]
-        for j in range(r1 - r0):
-            s = lv.run_starts[r0 + j]
-            ln = lv.run_lens[r0 + j]
-            gather[lvl, j, :ln] = lv.esrc[s:s + ln]
-            dsts[lvl, j] = lv.run_dst[r0 + j]
+    # every row's level and position in it: runs first, then the
+    # queue-only vertices of the level
+    run_level = np.repeat(np.arange(L), rcounts)
+    run_pos = np.arange(len(run_level)) - lv.run_ptr[run_level]
+    q_level = np.repeat(np.arange(L), qcounts)
+    q_pos = (np.arange(len(q_level)) - qptr[q_level]
+             + rcounts[q_level])
+    # every edge's run and offset in it
+    edge_run = np.repeat(np.arange(len(lv.run_lens)), lv.run_lens)
+    edge_off = np.arange(len(edge_run)) - lv.run_starts[edge_run]
+    rptr, eptr = lv.run_ptr, lv.elevel_ptr
+    segments = []
+    for a, b, R in _band_segments(rcounts[1:] + qcounts[1:]):
+        a, b = a + 1, b + 1                     # level 0 has no rows
+        gather = np.full((b - a, R, Dmax), -1, dtype=np.int32)
+        dsts = np.full((b - a, R), -1, dtype=np.int32)
+        r = slice(rptr[a], rptr[b])
+        dsts[run_level[r] - a, run_pos[r]] = lv.run_dst[r]
+        e = slice(eptr[a], eptr[b])
+        er = edge_run[e]
+        gather[run_level[er] - a, run_pos[er], edge_off[e]] = lv.esrc[e]
         if lv.qonly_ptr is not None:
-            q0, q1 = lv.qonly_ptr[lvl], lv.qonly_ptr[lvl + 1]
-            dsts[lvl, r1 - r0:r1 - r0 + (q1 - q0)] = lv.qonly_dst[q0:q1]
-    return gather, dsts
+            q = slice(qptr[a], qptr[b])
+            dsts[q_level[q] - a, q_pos[q]] = lv.qonly_dst[q]
+        segments.append((gather, dsts))
+    return tuple(segments)
 
 
 def _pallas_interpret() -> bool:
@@ -602,16 +675,16 @@ def _pallas_level_step(seg, idx, fq, base, clamp: bool, has_q: bool,
 def _level_loop(has_q: bool, clamp: bool, want_r: bool):
     """The device level loop for one flag set, un-jitted.
 
-    ``run(F, R, gather, dsts, qpred)`` walks levels 1..L-1 with a
-    ``fori_loop``: gather the predecessor rows, call the Pallas step,
-    scatter the new rows back.  The graph arrays are arguments, so one
-    jitted ``run`` re-specializes per plan shape on its own."""
+    ``run(F, R, segments, qpred)`` walks the plan's segments
+    (``_jax_padded``) in order, each with a ``fori_loop`` over its
+    levels: gather the predecessor rows, call the Pallas step, scatter
+    the new rows back.  F and R stay on the device from one segment to
+    the next.  The graph arrays are arguments, so one jitted ``run``
+    re-specializes per plan shape on its own."""
     import jax
     import jax.numpy as jnp
 
-    def run(Fin, Rin, gat, dst_pad, qpred):
-        L = gat.shape[0]
-
+    def walk(gat, dst_pad, qpred):
         def body(lvl, carry):
             Fcur, Rcur = carry
             g = gat[lvl]                        # (R, D)
@@ -629,8 +702,14 @@ def _level_loop(has_q: bool, clamp: bool, want_r: bool):
             if want_r:
                 Rcur = Rcur.at[dc].set(jnp.where(keep, r, Rcur[dc]))
             return Fnext, Rcur
+        return body
 
-        return jax.lax.fori_loop(1, L, body, (Fin, Rin))
+    def run(Fin, Rin, segments, qpred):
+        carry = (Fin, Rin)
+        for gat, dst_pad in segments:
+            carry = jax.lax.fori_loop(0, gat.shape[0],
+                                      walk(gat, dst_pad, qpred), carry)
+        return carry
 
     run.__name__ = run.__qualname__ = LEVEL_LOOP_NAME[len("jit_"):]
     return run
@@ -675,7 +754,7 @@ def _accumulate_jax(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
         fault_hook()
 
     configure_compile_cache()
-    gather, dsts = _jax_padded(lv)
+    segments = _jax_padded(lv)
     has_q = lv.qpred is not None
     want_r = R_out is not None
     qp = np.asarray(lv.qpred if has_q else np.zeros(1, dtype=np.int32),
@@ -693,21 +772,24 @@ def _accumulate_jax(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
     _JAX_CACHE.move_to_end(key)
     while len(_JAX_CACHE) > _JAX_CACHE_CAP:
         _JAX_CACHE.popitem(last=False)
-    L, Rmax, Dmax = gather.shape
+    shapes = [g.shape for g, _ in segments]
+    slots = sum(Ls * Rs * D for Ls, Rs, D in shapes)
     moved = F.nbytes + (R_out.nbytes if want_r else 0)
     try:
-        with span("replay.upload", bytes=moved + gather.nbytes + dsts.nbytes
-                  + qp.nbytes):
+        with span("replay.upload", bytes=moved + qp.nbytes + sum(
+                g.nbytes + d.nbytes for g, d in segments)):
             Rin = jnp.asarray(R_out) if want_r else jnp.zeros(
                 (1, F.shape[1]), dtype=F.dtype)
-            args = (jnp.asarray(F), Rin, jnp.asarray(gather),
-                    jnp.asarray(dsts), jnp.asarray(qp))
+            args = (jnp.asarray(F), Rin,
+                    tuple((jnp.asarray(g), jnp.asarray(d))
+                          for g, d in segments), jnp.asarray(qp))
         # the call returns once the loop is dispatched; the first read
         # of a result (the column maxima, else the download) waits for
         # the device to finish it
-        with span("replay.run", levels=max(L - 1, 0), rows=Rmax,
-                  width=Dmax, edges=len(lv.esrc),
-                  slots=max(L - 1, 0) * Rmax * Dmax):
+        with span("replay.run", levels=max(lv.n_levels - 1, 0),
+                  rows=max((sh[1] for sh in shapes), default=0),
+                  width=shapes[0][2] if shapes else 0,
+                  edges=len(lv.esrc), slots=slots, segments=len(shapes)):
             Fj, Rj = fn(*args)
             colmax = None if land is None else _column_absmax(Fj)
         dst = land(colmax) if land is not None else (F, R_out)
@@ -718,10 +800,13 @@ def _accumulate_jax(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
     except Exception as exc:
         raise DeviceReplayError(
             f"device level pass failed on {jax.default_backend()} for plan "
-            f"(L, Rmax, Dmax)={gather.shape}, rows={F.shape[0]}, "
+            f"segments (L, Rmax, Dmax)={shapes}, rows={F.shape[0]}, "
             f"k={F.shape[1]}, dtype={F.dtype}, has_q={has_q}, "
             f"clamp={clamp}, want_r={want_r}: {type(exc).__name__}: "
             f"{exc}") from exc
+    stats.add("replay_edges", len(lv.esrc))
+    stats.add("replay_slots", slots)
+    stats.add("replay_segments", len(shapes))
     return dst[0]
 
 

@@ -352,8 +352,10 @@ def _int_case(seed: int = 3, k: int = 3):
 
 def test_padded_rows_fill_whole_lanes():
     lv, _ = _int_case()
-    gather, dsts = bk._jax_padded(lv)
-    assert gather.shape[1] % 128 == 0 and dsts.shape == gather.shape[:2]
+    segments = bk._jax_padded(lv)
+    assert segments
+    for gather, dsts in segments:
+        assert gather.shape[1] % 128 == 0 and dsts.shape == gather.shape[:2]
 
 
 def test_row_block_tiles_the_level():

@@ -84,13 +84,15 @@ def profiled(tmp_path_factory):
 
     def spy(lv, F, clamp=True, R_out=None, land=None):
         res = real(lv, F, clamp=clamp, R_out=R_out, land=land)
-        gather, dsts = bk._jax_padded(lv)
+        segments = bk._jax_padded(lv)
         qp = np.asarray(lv.qpred if lv.qpred is not None
                         else np.zeros(1, dtype=np.int32), dtype=np.int32)
         moved = F.nbytes + (R_out.nbytes if R_out is not None else 0)
-        passes.append({"upload": moved + gather.nbytes + dsts.nbytes
+        passes.append({"upload": moved + sum(g.nbytes + d.nbytes
+                                             for g, d in segments)
                        + qp.nbytes, "download": moved,
-                       "shape": gather.shape, "edges": len(lv.esrc)})
+                       "shapes": [g.shape for g, _ in segments],
+                       "levels": lv.n_levels - 1, "edges": len(lv.esrc)})
         return res
     mp.setattr(bk, "_accumulate_jax", spy)
     log_dir = str(root / "profile")
@@ -153,9 +155,12 @@ def test_run_span_counts_the_padded_level_rectangle(profiled):
     runs = [s["stats"] for s in spans if s["name"] == "replay.run"]
     assert len(runs) == len(passes) > 0
     for st, p in zip(runs, passes):
-        L, Rmax, Dmax = p["shape"]
-        assert (st["levels"], st["rows"], st["width"]) == (L - 1, Rmax, Dmax)
-        assert st["slots"] == (L - 1) * Rmax * Dmax
+        shapes = p["shapes"]
+        assert st["levels"] == sum(Ls for Ls, _, _ in shapes) == p["levels"]
+        assert st["rows"] == max(Rs for _, Rs, _ in shapes)
+        assert {st["width"]} == {D for _, _, D in shapes}
+        assert st["segments"] == len(shapes)
+        assert st["slots"] == sum(Ls * Rs * D for Ls, Rs, D in shapes)
         assert st["edges"] == p["edges"] <= st["slots"]
 
 
@@ -182,7 +187,7 @@ def test_replay_span_says_whether_the_chunk_was_whole(profiled):
 
 def test_no_span_is_opened_per_level(profiled):
     spans, passes, _ = profiled
-    levels = sum(p["shape"][0] - 1 for p in passes)
+    levels = sum(p["levels"] for p in passes)
     assert len(spans) < levels
 
 
@@ -232,6 +237,24 @@ def test_level_loop_program_is_named_by_the_constant():
     gat = jnp.full((3, 8, 2), -1, jnp.int32)
     dst = jnp.full((3, 8), -1, jnp.int32)
     lowered = jax.jit(bk._level_loop(True, False, True)).lower(
-        F, F, gat, dst, jnp.zeros(5, jnp.int32))
+        F, F, ((gat, dst), (gat[:1], dst[:1])), jnp.zeros(5, jnp.int32))
     name = re.match(r"module @(\S+)", lowered.as_text()).group(1)
     assert name == bk.LEVEL_LOOP_NAME == "jit_run"
+
+
+def test_replay_counters_add_up_the_run_spans(tmp_path, monkeypatch):
+    """``backend.stats`` counts the edges, padded slots and segments of
+    every device pass as the ``edan.replay.run`` spans state them."""
+    monkeypatch.setenv("EDAN_SCHEDULE_CACHE", str(tmp_path / "sched"))
+    log_dir = str(tmp_path / "profile")
+    before = bk.stats.snapshot()
+    with jax.profiler.trace(log_dir):
+        grid_report(trace_kernel("lu", 6), ALPHAS[:2], ms=(2, 4),
+                    simulate_points=True, backend="jax")
+    after = bk.stats.snapshot()
+    runs = [s["stats"] for s in _read_spans(log_dir)
+            if s["name"] == "replay.run"]
+    assert runs
+    for counter, stat in (("replay_edges", "edges"), ("replay_slots", "slots"),
+                          ("replay_segments", "segments")):
+        assert after[counter] - before[counter] == sum(r[stat] for r in runs)

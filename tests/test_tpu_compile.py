@@ -8,12 +8,15 @@ that does not fit the device's memory — so these tests guard the device
 path at no chip time.  A passing compile says nothing about results or
 speed.
 
-Shapes are plans the chip smoke run drives, as ``_jax_padded`` pads them
+Shapes are plans the chip smoke run drives, each padded to one width
 (the row axis to a multiple of 128, or of 512 past it): the PAPER_15
 PolyBench union at N=20, m=8 (both compute-slot variants merged, 11
 alphas; 449 rows wide before padding), the HPCG CG trace at n=8, 3
 iterations (3 alphas), and the service's union of kernel, CG and model
-traces, whose analytic sweep has levels 22,016 rows wide (9 alphas).
+traces, whose analytic sweep has levels 22,016 rows wide (9 alphas); and
+the plan of the benchmark's HPCG cell as ``_jax_padded`` cuts it into
+segments of one band each: CG at n=16, 1 iteration, m=4 (11 alphas),
+46,871 levels of which 4 are 4,096 or 8,192 rows wide.
 Each is compiled under the two flag sets in use: the batched simulator's
 replay (slot chains and ready times, no clamp) and the analytic sweep
 (clamp, no slot chain, no ready times).  Each compile must hold the
@@ -33,11 +36,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import backend as bk
 
-# (name, rows, L, Rmax, Dmax, k)
+# (name, rows, segments [(levels, rows)], Dmax, k)
 PLANS = [
-    ("paper15_m8", 1_108_760, 34_506, 512, 2, 11),
-    ("hpcg_n8_iters3", 104_342, 14_928, 1024, 2, 3),
-    ("service_union", 216_331, 5_247, 22_016, 7, 9),
+    ("paper15_m8", 1_108_760, [(34_506, 512)], 2, 11),
+    ("hpcg_n8_iters3", 104_342, [(14_928, 1024)], 2, 3),
+    ("service_union", 216_331, [(5_247, 22_016)], 7, 9),
+    ("hpcg16_banded", 321_650, [(39_696, 128), (2, 8192), (6_147, 128),
+                                (2, 4096), (1_024, 128)], 2, 11),
 ]
 # (has_q, clamp, want_r)
 FLAGS = [
@@ -77,7 +82,7 @@ def compiled_for_chip(monkeypatch):
 @pytest.mark.parametrize("plan", PLANS, ids=[p[0] for p in PLANS])
 def test_level_loop_compiles_for_v5e(one_chip, compiled_for_chip, plan,
                                      flags):
-    _, rows, L, rmax, dmax, k = plan
+    _, rows, segments, dmax, k = plan
     has_q, clamp, want_r = flags
 
     def arg(shape, dtype):
@@ -86,8 +91,8 @@ def test_level_loop_compiles_for_v5e(one_chip, compiled_for_chip, plan,
     n = rows + 1 if has_q else rows     # slot chains add a sentinel row
     args = (arg((n, k), jnp.float32),
             arg((n, k) if want_r else (1, k), jnp.float32),
-            arg((L, rmax, dmax), jnp.int32),
-            arg((L, rmax), jnp.int32),
+            tuple((arg((L, R, dmax), jnp.int32), arg((L, R), jnp.int32))
+                  for L, R in segments),
             arg((rows,) if has_q else (1,), jnp.int32))
     run = bk._level_loop(has_q, clamp, want_r)
     compiled = jax.jit(run).lower(*args).compile()
@@ -100,7 +105,7 @@ def test_column_absmax_compiles_for_v5e(one_chip, compiled_for_chip, plan):
     """The float32 certificate's device reduction (``_column_absmax``)
     over a plan's finish matrix: k values out, no temp the size of the
     matrix."""
-    _, rows, _, _, _, k = plan
+    _, rows, _, _, k = plan
     x = jax.ShapeDtypeStruct((rows + 1, k), jnp.float32, sharding=one_chip)
     compiled = bk._absmax_program().lower(x).compile()
     assert compiled.out_info.shape == (k,)
