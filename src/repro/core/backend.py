@@ -55,7 +55,7 @@ import functools
 import os
 from collections import OrderedDict
 from dataclasses import dataclass
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -82,15 +82,16 @@ _REPLAY_DTYPES = ("float32", "float64")
 #: exactly (no x64 flag, or a TPU).  Each device level pass
 #: (``_accumulate_jax``) adds its predecessor edges to ``replay_edges``,
 #: the slots of its padded gather tensors (levels x rows x width, summed
-#: over the plan's segments) to ``replay_slots`` and its segment count to
-#: ``replay_segments``.  Thread-safe: the
+#: over the plan's segments) to ``replay_slots``, its segment count to
+#: ``replay_segments`` and the levels it wrote, each as one slice of the
+#: level-major F and R, to ``replay_slice_levels``.  Thread-safe: the
 #: analysis service replays concurrent batches, and lost increments here
 #: would skew the very counters its benchmarks and fault-injection gates
 #: assert on.
 stats = Stats(chunks=0, jax_chunks=0, jax_f64_chunks=0, numpy_chunks=0,
               certified_columns=0, demoted_columns=0, f32_whole_chunks=0,
               numpy_f64_passes=0, replay_edges=0, replay_slots=0,
-              replay_segments=0)
+              replay_segments=0, replay_slice_levels=0)
 
 #: Fault-injection hook (``serve.faults``): when set, called with no
 #: arguments at the top of the jax kernel path.  An exception it raises
@@ -250,7 +251,7 @@ class LevelCSR:
     qonly_ptr: Optional[np.ndarray] = None
     qonly_dst: Optional[np.ndarray] = None
     seg_ptr: Optional[np.ndarray] = None    # block boundaries (union graphs)
-    jax_padded: Optional[tuple] = None      # memoized padded segments
+    jax_padded: Optional[tuple] = None      # memoized LevelPlan
 
     def level_maxlens(self) -> list:
         if self.run_maxlen is None:
@@ -486,17 +487,43 @@ def _row_block(R: int, D: int, k: int) -> int:
     return tr
 
 
-def _jax_padded(lv: LevelCSR):
+class LevelPlan(NamedTuple):
+    """The device level loop's plan (``_jax_padded``), in level-major rows.
+
+    The loop runs on F and R with their rows permuted so that each
+    level's destinations are one contiguous run: row ``p`` of the
+    permuted matrix holds vertex ``perm[p]``, and vertex ``v`` sits at
+    row ``pos[v]``.  First come the vertices no level writes (level 0 in
+    id order), then level 1's rows, level 2's, and so on, each level's
+    runs first and then its queue-only vertices.  The loop adds as many
+    pad rows after them as the widest segment's band, so that a level's
+    padded slice neither runs off the end nor reaches the rows at and
+    past ``n`` (the zero sentinel of the slot chains).  Those follow the
+    pad, each at its own index plus the pad, so one plan serves F with or
+    without them.
+
+    ``segments`` cuts levels 1..L-1 into contiguous runs of one padded
+    width ``Rs`` (``_band_segments``), each ``(gather, qg, off)``: the
+    (Ls, Rs, Dmax) gather index of every row's predecessors, the (Ls, Rs)
+    row of its queue predecessor (``None`` without slot chains) and the
+    (Ls,) first row of each level, all in level-major rows; ``-1`` marks
+    a gather slot of no edge."""
+
+    perm: Optional[np.ndarray]
+    pos: Optional[np.ndarray]
+    segments: tuple
+
+
+def _jax_padded(lv: LevelCSR) -> LevelPlan:
     """Pad the per-level runs to rectangles for the jitted level loop.
 
-    Returns the plan's segments, ``((gather, dsts), ...)``: levels 1..L-1
-    in order, cut into contiguous segments (``_band_segments``), each
-    padded to its own row width ``Rs`` as an (Ls, Rs, Dmax) gather tensor
-    and an (Ls, Rs) ``dsts``.  Queue-only vertices (no DAG predecessor,
-    just a slot chain) become zero-width runs -- their reduce sees only
-    the folded-in qpred entry.  The padded tensors depend only on the
-    partition, so they are memoized on the LevelCSR (chunked sweeps call
-    the kernel several times)."""
+    Returns the ``LevelPlan``: levels 1..L-1 in level-major rows, cut
+    into contiguous segments (``_band_segments``), each padded to its own
+    row width.  Queue-only vertices (no DAG predecessor, just a slot
+    chain) become zero-width runs -- their reduce sees only the folded-in
+    queue predecessor.  The plan depends only on the partition, so it is
+    memoized on the LevelCSR (chunked sweeps call the kernel several
+    times)."""
     if lv.jax_padded is not None:
         return lv.jax_padded
     with span("replay.pad", levels=max(lv.n_levels - 1, 0)):
@@ -562,41 +589,61 @@ def _band_segments(width) -> list:
     return [tuple(s) for s in segs]
 
 
-def _pad_levels(lv: LevelCSR):
-    L = lv.n_levels
+def _pad_levels(lv: LevelCSR) -> LevelPlan:
+    L, n = lv.n_levels, lv.n
     if L <= 1:
-        return ()
+        return LevelPlan(None, None, ())
     rcounts = np.diff(lv.run_ptr).astype(np.int64)
     qptr = (lv.qonly_ptr.astype(np.int64) if lv.qonly_ptr is not None
             else np.zeros(L + 1, dtype=np.int64))
     qcounts = np.diff(qptr)
+    width = rcounts + qcounts
     Dmax = int(lv.run_lens.max()) if len(lv.run_lens) else 1
     # every row's level and position in it: runs first, then the
-    # queue-only vertices of the level
+    # queue-only vertices of the level; level l's rows start at start[l],
+    # after the vertices no level writes
+    start = (n - int(width.sum())) + np.concatenate(([0], np.cumsum(width)))
     run_level = np.repeat(np.arange(L), rcounts)
     run_pos = np.arange(len(run_level)) - lv.run_ptr[run_level]
     q_level = np.repeat(np.arange(L), qcounts)
     q_pos = (np.arange(len(q_level)) - qptr[q_level]
              + rcounts[q_level])
+    qdst = (lv.qonly_dst if lv.qonly_dst is not None
+            else np.zeros(0, dtype=np.int32))
+    written = np.zeros(n, dtype=bool)
+    written[lv.run_dst] = True
+    written[qdst] = True
+    perm = np.empty(n, dtype=np.int32)
+    perm[:start[0]] = np.flatnonzero(~written)
+    perm[start[run_level] + run_pos] = lv.run_dst
+    perm[start[q_level] + q_pos] = qdst
+    pos = np.empty(n, dtype=np.int32)
+    pos[perm] = np.arange(n, dtype=np.int32)
     # every edge's run and offset in it
     edge_run = np.repeat(np.arange(len(lv.run_lens)), lv.run_lens)
     edge_off = np.arange(len(edge_run)) - lv.run_starts[edge_run]
-    rptr, eptr = lv.run_ptr, lv.elevel_ptr
+    eptr = lv.elevel_ptr
+    bands = [(a + 1, b + 1, R)                  # level 0 has no rows
+             for a, b, R in _band_segments(width[1:])]
+    pad = max(R for _, _, R in bands)
     segments = []
-    for a, b, R in _band_segments(rcounts[1:] + qcounts[1:]):
-        a, b = a + 1, b + 1                     # level 0 has no rows
+    for a, b, R in bands:
         gather = np.full((b - a, R, Dmax), -1, dtype=np.int32)
-        dsts = np.full((b - a, R), -1, dtype=np.int32)
-        r = slice(rptr[a], rptr[b])
-        dsts[run_level[r] - a, run_pos[r]] = lv.run_dst[r]
         e = slice(eptr[a], eptr[b])
         er = edge_run[e]
-        gather[run_level[er] - a, run_pos[er], edge_off[e]] = lv.esrc[e]
-        if lv.qonly_ptr is not None:
-            q = slice(qptr[a], qptr[b])
-            dsts[q_level[q] - a, q_pos[q]] = lv.qonly_dst[q]
-        segments.append((gather, dsts))
-    return tuple(segments)
+        gather[run_level[er] - a, run_pos[er], edge_off[e]] = pos[lv.esrc[e]]
+        qg = None
+        if lv.qpred is not None:
+            # each row's queue predecessor, renumbered: a vertex to its
+            # row, the sentinel (and any row past n) past the pad
+            rows = slice(start[a], start[b])
+            lvl = np.repeat(np.arange(b - a), width[a:b])
+            q = lv.qpred[perm[rows]].astype(np.int64)
+            qg = np.zeros((b - a, R), dtype=np.int32)
+            qg[lvl, np.arange(len(lvl)) - (start[a:b][lvl] - start[a])] = \
+                np.where(q < n, pos[np.minimum(q, n - 1)], q + pad)
+        segments.append((gather, qg, start[a:b].astype(np.int32)))
+    return LevelPlan(perm, pos, tuple(segments))
 
 
 def _pallas_interpret() -> bool:
@@ -672,44 +719,76 @@ def _pallas_level_step(seg, idx, fq, base, clamp: bool, has_q: bool,
     return res if want_r else (res, None)
 
 
+#: What the level-major F and R hold in their pad rows (``LevelPlan``)
+#: before the loop writes over them.  No gather reads a pad row and the
+#: loop's result leaves them out, so any value gives the same answer.
+_PAD_FILL = 0.0
+
+
 def _level_loop(has_q: bool, clamp: bool, want_r: bool):
     """The device level loop for one flag set, un-jitted.
 
-    ``run(F, R, segments, qpred)`` walks the plan's segments
-    (``_jax_padded``) in order, each with a ``fori_loop`` over its
-    levels: gather the predecessor rows, call the Pallas step, scatter
-    the new rows back.  F and R stay on the device from one segment to
-    the next.  The graph arrays are arguments, so one jitted ``run``
-    re-specializes per plan shape on its own."""
+    ``run(F, R, plan)`` lays F (and R, when ``want_r``) out in the
+    plan's level-major rows (``LevelPlan``), walks its segments in order,
+    each with a ``fori_loop`` over its levels, and returns them in vertex
+    order.  A level gathers its predecessor rows, calls the Pallas step
+    and writes its rows back as one slice: no level scatters.  F and R
+    stay on the device from one segment to the next.  The plan's arrays
+    are arguments, so one jitted ``run`` re-specializes per plan shape on
+    its own."""
     import jax
     import jax.numpy as jnp
+    from jax import lax
 
-    def walk(gat, dst_pad, qpred):
+    def walk(B, gat, qg, off):
+        Rs = gat.shape[1]
+
         def body(lvl, carry):
             Fcur, Rcur = carry
-            g = gat[lvl]                        # (R, D)
-            d = dst_pad[lvl]                    # (R,)
-            seg = Fcur[jnp.maximum(g, 0)]       # (R, D, k)
-            dc = jnp.maximum(d, 0)
+            g = gat[lvl]                        # (Rs, D)
+            at = (off[lvl], jnp.int32(0))     # one index dtype under x64
+            # the level's bases come from the level-major input B, which
+            # the loop never writes.  (A slice of the carried F makes XLA
+            # lay F out for the slice and relayout all of it for every
+            # level's gather, on a v5e.)
+            base = lax.dynamic_slice(B, at, (Rs, B.shape[1]))
+            seg = Fcur[jnp.maximum(g, 0)]       # (Rs, D, k)
             # the queue predecessor's finish (slot chain); missing
             # predecessors hit the zero sentinel row, i.e. a slot that
             # is free at t=0
-            fq = Fcur[qpred[dc]] if has_q else Fcur[dc]
-            new, r = _pallas_level_step(seg, g, fq, Fcur[dc], clamp,
-                                        has_q, want_r)
-            keep = (d >= 0)[:, None]
-            Fnext = Fcur.at[dc].set(jnp.where(keep, new, Fcur[dc]))
+            fq = Fcur[qg[lvl]] if has_q else base
+            new, r = _pallas_level_step(seg, g, fq, base, clamp, has_q,
+                                        want_r)
+            # past the level's width the slice covers later levels' rows,
+            # each written again at its own level from its base in B, and
+            # the pad: no gather reads them before, so they take any value
+            Fcur = lax.dynamic_update_slice(Fcur, new, at)
             if want_r:
-                Rcur = Rcur.at[dc].set(jnp.where(keep, r, Rcur[dc]))
-            return Fnext, Rcur
+                Rcur = lax.dynamic_update_slice(Rcur, r, at)
+            return Fcur, Rcur
         return body
 
-    def run(Fin, Rin, segments, qpred):
-        carry = (Fin, Rin)
-        for gat, dst_pad in segments:
-            carry = jax.lax.fori_loop(0, gat.shape[0],
-                                      walk(gat, dst_pad, qpred), carry)
-        return carry
+    def run(Fin, Rin, plan):
+        perm, pos, segments = plan
+        if not segments:
+            return Fin, Rin
+        n = perm.shape[0]
+        pad = max(gat.shape[1] for gat, *_ in segments)
+
+        def to_levels(X):
+            return jnp.concatenate(
+                [X[perm], jnp.full((pad, X.shape[1]), _PAD_FILL, X.dtype),
+                 X[n:]])
+
+        def to_vertices(X):
+            return jnp.concatenate([X[pos], X[n + pad:]])
+
+        B = to_levels(Fin)
+        carry = (B, to_levels(Rin) if want_r else Rin)
+        for seg in segments:
+            carry = lax.fori_loop(0, seg[0].shape[0], walk(B, *seg), carry)
+        Fout, Rout = carry
+        return to_vertices(Fout), to_vertices(Rout) if want_r else Rout
 
     run.__name__ = run.__qualname__ = LEVEL_LOOP_NAME[len("jit_"):]
     return run
@@ -754,12 +833,10 @@ def _accumulate_jax(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
         fault_hook()
 
     configure_compile_cache()
-    segments = _jax_padded(lv)
+    plan = _jax_padded(lv)
     has_q = lv.qpred is not None
     want_r = R_out is not None
-    qp = np.asarray(lv.qpred if has_q else np.zeros(1, dtype=np.int32),
-                    dtype=np.int32)
-    # the traced function depends only on these flags (the graph arrays
+    # the traced function depends only on these flags (the plan's arrays
     # are arguments, so jax.jit re-specializes per shape on its own); the
     # dtype and x64 flag are part of the key so f32 replays, f64 analytic
     # sweeps and x64-mode replays each get their own bounded slot
@@ -772,24 +849,26 @@ def _accumulate_jax(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
     _JAX_CACHE.move_to_end(key)
     while len(_JAX_CACHE) > _JAX_CACHE_CAP:
         _JAX_CACHE.popitem(last=False)
-    shapes = [g.shape for g, _ in segments]
+    shapes = [seg[0].shape for seg in plan.segments]
+    levels = sum(sh[0] for sh in shapes)
     slots = sum(Ls * Rs * D for Ls, Rs, D in shapes)
     moved = F.nbytes + (R_out.nbytes if want_r else 0)
+    leaves = jax.tree_util.tree_leaves(plan)
     try:
-        with span("replay.upload", bytes=moved + qp.nbytes + sum(
-                g.nbytes + d.nbytes for g, d in segments)):
+        with span("replay.upload",
+                  bytes=moved + sum(a.nbytes for a in leaves)):
             Rin = jnp.asarray(R_out) if want_r else jnp.zeros(
                 (1, F.shape[1]), dtype=F.dtype)
-            args = (jnp.asarray(F), Rin,
-                    tuple((jnp.asarray(g), jnp.asarray(d))
-                          for g, d in segments), jnp.asarray(qp))
+            args = (jnp.asarray(F), Rin, jax.tree_util.tree_map(
+                jnp.asarray, plan))
         # the call returns once the loop is dispatched; the first read
         # of a result (the column maxima, else the download) waits for
         # the device to finish it
-        with span("replay.run", levels=max(lv.n_levels - 1, 0),
+        with span("replay.run", levels=levels,
                   rows=max((sh[1] for sh in shapes), default=0),
                   width=shapes[0][2] if shapes else 0,
-                  edges=len(lv.esrc), slots=slots, segments=len(shapes)):
+                  edges=len(lv.esrc), slots=slots, segments=len(shapes),
+                  permuted=lv.n if shapes else 0):
             Fj, Rj = fn(*args)
             colmax = None if land is None else _column_absmax(Fj)
         dst = land(colmax) if land is not None else (F, R_out)
@@ -806,6 +885,7 @@ def _accumulate_jax(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
             f"{exc}") from exc
     stats.add("replay_edges", len(lv.esrc))
     stats.add("replay_slots", slots)
+    stats.add("replay_slice_levels", levels)
     stats.add("replay_segments", len(shapes))
     return dst[0]
 

@@ -352,10 +352,11 @@ def _int_case(seed: int = 3, k: int = 3):
 
 def test_padded_rows_fill_whole_lanes():
     lv, _ = _int_case()
-    segments = bk._jax_padded(lv)
+    segments = bk._jax_padded(lv).segments
     assert segments
-    for gather, dsts in segments:
-        assert gather.shape[1] % 128 == 0 and dsts.shape == gather.shape[:2]
+    for gather, qg, off in segments:
+        assert gather.shape[1] % 128 == 0 and qg is None
+        assert off.shape == gather.shape[:1]
 
 
 def test_row_block_tiles_the_level():

@@ -44,7 +44,7 @@ def checked(monkeypatch, tmp_path):
         assert np.array_equal(got_F, want_F)
         if want_R is not None:
             assert np.array_equal(got_R, want_R)
-        passes.append([g.shape for g, _ in bk._jax_padded(lv)])
+        passes.append([seg[0].shape for seg in bk._jax_padded(lv).segments])
         return out
     monkeypatch.setattr(bk, "_accumulate_jax", spy)
     return passes

@@ -84,14 +84,14 @@ def profiled(tmp_path_factory):
 
     def spy(lv, F, clamp=True, R_out=None, land=None):
         res = real(lv, F, clamp=clamp, R_out=R_out, land=land)
-        segments = bk._jax_padded(lv)
-        qp = np.asarray(lv.qpred if lv.qpred is not None
-                        else np.zeros(1, dtype=np.int32), dtype=np.int32)
+        plan = bk._jax_padded(lv)
+        arrays = [plan.perm, plan.pos] + [a for seg in plan.segments
+                                          for a in seg if a is not None]
         moved = F.nbytes + (R_out.nbytes if R_out is not None else 0)
-        passes.append({"upload": moved + sum(g.nbytes + d.nbytes
-                                             for g, d in segments)
-                       + qp.nbytes, "download": moved,
-                       "shapes": [g.shape for g, _ in segments],
+        passes.append({"upload": moved + sum(a.nbytes for a in arrays),
+                       "download": moved,
+                       "shapes": [seg[0].shape for seg in plan.segments],
+                       "vertices": lv.n,
                        "levels": lv.n_levels - 1, "edges": len(lv.esrc)})
         return res
     mp.setattr(bk, "_accumulate_jax", spy)
@@ -162,6 +162,7 @@ def test_run_span_counts_the_padded_level_rectangle(profiled):
         assert st["segments"] == len(shapes)
         assert st["slots"] == sum(Ls * Rs * D for Ls, Rs, D in shapes)
         assert st["edges"] == p["edges"] <= st["slots"]
+        assert st["permuted"] == p["vertices"]
 
 
 def test_demote_span_counts_the_demoted_columns(profiled):
@@ -235,16 +236,20 @@ def test_level_loop_program_is_named_by_the_constant():
     import jax.numpy as jnp
     F = jnp.zeros((5, 2), jnp.float32)
     gat = jnp.full((3, 8, 2), -1, jnp.int32)
-    dst = jnp.full((3, 8), -1, jnp.int32)
-    lowered = jax.jit(bk._level_loop(True, False, True)).lower(
-        F, F, ((gat, dst), (gat[:1], dst[:1])), jnp.zeros(5, jnp.int32))
+    qg = jnp.zeros((3, 8), jnp.int32)
+    off = jnp.zeros(3, jnp.int32)
+    plan = bk.LevelPlan(jnp.arange(4, dtype=jnp.int32),
+                        jnp.arange(4, dtype=jnp.int32),
+                        ((gat, qg, off), (gat[:1], qg[:1], off[:1])))
+    lowered = jax.jit(bk._level_loop(True, False, True)).lower(F, F, plan)
     name = re.match(r"module @(\S+)", lowered.as_text()).group(1)
     assert name == bk.LEVEL_LOOP_NAME == "jit_run"
 
 
 def test_replay_counters_add_up_the_run_spans(tmp_path, monkeypatch):
-    """``backend.stats`` counts the edges, padded slots and segments of
-    every device pass as the ``edan.replay.run`` spans state them."""
+    """``backend.stats`` counts the edges, padded slots, segments and
+    levels of every device pass as the ``edan.replay.run`` spans state
+    them: every level the device ran was written as one slice."""
     monkeypatch.setenv("EDAN_SCHEDULE_CACHE", str(tmp_path / "sched"))
     log_dir = str(tmp_path / "profile")
     before = bk.stats.snapshot()
@@ -256,5 +261,6 @@ def test_replay_counters_add_up_the_run_spans(tmp_path, monkeypatch):
             if s["name"] == "replay.run"]
     assert runs
     for counter, stat in (("replay_edges", "edges"), ("replay_slots", "slots"),
-                          ("replay_segments", "segments")):
+                          ("replay_segments", "segments"),
+                          ("replay_slice_levels", "levels")):
         assert after[counter] - before[counter] == sum(r[stat] for r in runs)

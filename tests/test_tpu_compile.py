@@ -28,6 +28,7 @@ one process at a time may load the TPU library, and test workers import
 every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,14 +90,20 @@ def test_level_loop_compiles_for_v5e(one_chip, compiled_for_chip, plan,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     n = rows + 1 if has_q else rows     # slot chains add a sentinel row
+    plan = bk.LevelPlan(
+        arg((rows,), jnp.int32), arg((rows,), jnp.int32),
+        tuple((arg((L, R, dmax), jnp.int32),
+               arg((L, R), jnp.int32) if has_q else None,
+               arg((L,), jnp.int32))
+              for L, R in segments))
     args = (arg((n, k), jnp.float32),
-            arg((n, k) if want_r else (1, k), jnp.float32),
-            tuple((arg((L, R, dmax), jnp.int32), arg((L, R), jnp.int32))
-                  for L, R in segments),
-            arg((rows,) if has_q else (1,), jnp.int32))
+            arg((n, k) if want_r else (1, k), jnp.float32), plan)
     run = bk._level_loop(has_q, clamp, want_r)
     compiled = jax.jit(run).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    # every level writes its rows as one slice of the level-major F and R
+    assert not re.search(r"\bscatter\(", hlo)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
