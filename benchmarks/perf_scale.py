@@ -20,7 +20,7 @@ Acceptance assertions (the reason this bench exists):
 * the ~1M tier's peak-RSS *delta* over the post-import baseline stays
   below **2x the theoretical working set**: the trace's CSR footprint
   (``EDag.array_nbytes`` — the int32 arrays actually installed) plus
-  the recorded replay plan's arrays (``_ReplayPlan.array_nbytes`` —
+  the recorded replay plan's arrays (``scheduler._Plan.array_nbytes`` —
   the order-augmented partition the simulator must keep to replay the
   sweep).  I.e. construction and replay never hold a *second* full
   copy of either structure;
@@ -133,9 +133,11 @@ def _child(cfg: dict) -> None:
 
     # the recorded plan is live working set too (augmented partition,
     # issue orders) — count it in the denominator of the peak bound
-    plan = scheduler._get_plan(g, ms[0], css[0], 1.0)
-    plan_bytes = sum(plan.array_nbytes().values()) if plan else 0
-    del plan
+    sched, plan, _ = scheduler._schedule(g, ms[0], css[0], 1.0, alphas[0],
+                                         lookup=True)
+    plan = plan or scheduler._build_plan([sched])
+    plan_bytes = sum(plan.array_nbytes().values())
+    del sched, plan
 
     legacy_ok = None
     if cfg.get("check_legacy"):

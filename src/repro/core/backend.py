@@ -230,12 +230,9 @@ class LevelCSR:
     ``qonly_dst`` partition by level the vertices whose only predecessor
     is their queue predecessor.
 
-    For a block-diagonal *union* graph (a multi-trace suite replay),
-    ``seg_ptr`` holds the (K+1,) block boundaries in row space.  Edges
-    and slot chains of such a partition never cross a boundary — each
-    member trace owns its own slot pool — so per-trace results fall out
-    of the shared row matrix via one segmented reduction
-    (``segment_max_rows``) instead of K kernel invocations.
+    A block-diagonal *union* graph (a multi-trace suite replay) is one
+    partition too; its block boundaries live on the replay plan
+    (``scheduler._Plan.seg_ptr``), not here.
     """
 
     n: int
@@ -250,7 +247,6 @@ class LevelCSR:
     qpred: Optional[np.ndarray] = None
     qonly_ptr: Optional[np.ndarray] = None
     qonly_dst: Optional[np.ndarray] = None
-    seg_ptr: Optional[np.ndarray] = None    # block boundaries (union graphs)
     jax_padded: Optional[tuple] = None      # memoized LevelPlan
 
     def level_maxlens(self) -> list:
@@ -921,7 +917,8 @@ def level_accumulate(lv: LevelCSR, F: np.ndarray, clamp: bool = True,
 
     This is the engine's one shared hot loop: the analytic latency sweeps
     (``EDag._accumulate_batch_nk``) and the batched §4 simulator replay
-    (``scheduler._ReplayPlan.replay``) both dispatch here.
+    (``scheduler._Plan.replay``, one trace or a union suite) both
+    dispatch here.
 
     Parameters
     ----------

@@ -123,8 +123,9 @@ def _delta_decode(deltas: np.ndarray) -> Optional[np.ndarray]:
     return arr.astype(np.int32)
 
 #: Cumulative per-process counters, for benchmarks and tests:
-#: ``memory_hits`` / ``disk_hits`` / ``misses`` count plan lookups in
-#: ``simulate_batch``; ``record_runs`` counts instrumented event-loop
+#: ``memory_hits`` / ``disk_hits`` / ``misses`` count schedule lookups
+#: (``scheduler._schedule``: single traces and suite blocks, scalar and
+#: class mode alike); ``record_runs`` counts instrumented event-loop
 #: recordings (the cost the cache exists to amortize); ``stores`` counts
 #: successful disk writes; ``quarantined`` counts corrupt entries moved
 #: aside to ``*.bad`` on load.  The time of each recording is the span

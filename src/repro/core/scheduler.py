@@ -44,10 +44,20 @@ The successor CSR and in-degree arrays are computed once at
 ``EDag._finalize`` and shared by every engine, so a latency sweep pays
 graph finalization exactly once.
 
+One replay engine serves a single trace and a multi-trace suite
+(``suite.py``) alike, in scalar and class mode: a replay plan
+(``_Plan``) is a list of blocks, one per (trace, m, compute_slots)
+recorded schedule, stacked block-diagonally into one level partition
+(``_build_plan``); a single trace's plan is one block.  ``_schedule``
+finds a block's schedule (memo, then disk, then one recording) and
+``_replay_verify`` replays a plan chunk by chunk, verifies every block
+per point and reduces per-block makespans.  The single-trace straggler
+loop (``_batch_uniq``) and the suite's union groups sit on top.
+
 Recorded schedules are reused at three tiers: within one call (all alpha
 points share one plan), within one process (a small per-``EDag`` LRU of
-``_ReplayPlan`` objects, so grids over (m, compute_slots) and repeated
-sweeps skip re-recording), and across processes (the persistent
+one-block plans, so grids over (m, compute_slots) and repeated sweeps
+skip re-recording), and across processes (the persistent
 ``schedule_cache``, keyed by ``(trace digest, m, compute_slots)``).
 Every reused schedule goes through the same per-point ``(R, E, vid)``
 verification as a fresh one, so reuse can never change results — points
@@ -70,16 +80,8 @@ from . import backend as _bk
 from . import schedule_cache as _sc
 from .counters import span
 from .graph import EDag
-from .plan import (REPLAY_BYTES_PER_CELL, REPLAY_MEM_BUDGET, ExecPolicy,
-                   SweepSpec, replay_mem_budget)
+from .plan import ExecPolicy, SweepSpec
 
-# Budget constants and the env-resolution rule live in ``plan`` now (one
-# accounting rule shared by the chunk divisor, the suite's grouping rule
-# and the service's admission packing); the historical underscored names
-# stay importable for external callers and tests.
-_REPLAY_MEM_BUDGET = REPLAY_MEM_BUDGET
-_REPLAY_BYTES_PER_CELL = REPLAY_BYTES_PER_CELL
-_replay_mem_budget = replay_mem_budget
 # Below this many sweep points the recording run cannot amortize.
 _MIN_BATCH_POINTS = 2
 # Per-EDag in-process plan memo: one entry per (m, compute_slots) pair.
@@ -315,9 +317,7 @@ def _slot_qpred(rank: np.ndarray, O_mem: np.ndarray, O_alu: np.ndarray,
 
     ``qpred[r]`` is the rank of the vertex issued ``m`` (or ``cs``) slots
     earlier on the same resource class; vertices without one point at the
-    zero sentinel row ``n`` (a slot that is free at t=0).  Chains are
-    built per issue order, so in a multi-trace union (one order per
-    member trace) they can never cross block boundaries.  int32 like
+    zero sentinel row ``n`` (a slot that is free at t=0).  int32 like
     every other index array — the sentinel ``n`` fits because eDAG
     growth is guarded at the 2^31 boundary."""
     qpred = np.full(n, n, dtype=np.int32)
@@ -352,8 +352,6 @@ def _prov_check_arrays(prov: np.ndarray, m: int):
     """Verification scaffolding for a recorded slot-provenance array:
     ``(prov_ok, t_chk, need_chk)`` as ``_verify_slots`` consumes them.
 
-    Shared by the single-trace class plan and the union suite's class
-    blocks, so both certify recorded provenance with the identical rule.
     ``prov_ok`` is the structural screen — greedy pops the m initial
     zeros first (every finish is positive), then only real finishes;
     ``pop_step[i]`` is the issue step whose service popped i's finish (W
@@ -404,60 +402,144 @@ def _attach_queue_partition(lv, dst_r: np.ndarray, qpred: np.ndarray,
         lv.qonly_dst = qonly.astype(np.int32)
 
 
-class _ReplayPlan:
-    """Recorded schedule of one master run, ready for batched replay.
+class _Block:
+    """One (trace, m, compute_slots) block of a replay plan: its recorded
+    schedule and what the per-point verification reads, in the trace's
+    own rank space — the block's F/R rows ``[off, off + n)`` index with
+    ``rank`` / ``Om_rel`` / ``Oa_rel`` directly.  ``level`` is the
+    augmented graph's level assignment (what the disk tier persists).
+    Class-mode blocks also carry the recorded slot provenance and its
+    verification scaffolding (``_prov_check_arrays``)."""
 
-    Holds the order-augmented eDAG in pop-order relabeling (a topological
-    order of the augmented graph) as a ``backend.LevelCSR``, plus the issue
-    orders and the arrays the per-point order verification needs.
+    __slots__ = ("g", "m", "cs", "off", "topo", "rank", "O_mem", "O_alu",
+                 "Om_rel", "Oa_rel", "prov", "level", "prov_ok", "t_chk",
+                 "need_chk")
 
-    ``level`` may carry a previously persisted level assignment of the
-    augmented graph (from the schedule cache); it is validated against
-    the augmented edges and recomputed if it does not respect them, so a
-    corrupt cache entry degrades to a fresh ``levelize``, never to a
-    wrong evaluation order."""
-
-    __slots__ = ("n", "m", "cs", "topo", "rank", "lv", "is_mem_topo",
-                 "O_mem", "O_alu", "Om_rel", "Oa_rel", "level_aug",
-                 "prov", "cls_topo", "prov_ok", "t_chk", "need_chk")
-
-    def __init__(self, g: EDag, topo: np.ndarray, O_mem: np.ndarray,
-                 O_alu: np.ndarray, m: int, cs: int,
-                 level: Optional[np.ndarray] = None,
-                 prov: Optional[np.ndarray] = None,
-                 classes: Optional[np.ndarray] = None):
-        n = g.n_vertices
-        self.n, self.m, self.cs = n, m, cs
-        # the recorded pop order (finish time, vid) is a linear extension
-        # of the augmented DAG: slot chains strictly increase finish times
-        rank = np.empty(n, dtype=np.int32)
-        rank[topo] = np.arange(n, dtype=np.int32)
+    def __init__(self, g: EDag, m: int, cs: int, off: int, topo, rank,
+                 O_mem, O_alu, prov, level):
+        self.g, self.m, self.cs, self.off = g, m, cs, off
         self.topo, self.rank = topo, rank
         self.O_mem, self.O_alu = O_mem, O_alu
         self.Om_rel = rank[O_mem]
         self.Oa_rel = rank[O_alu] if cs else np.zeros(0, dtype=np.int32)
-        self.is_mem_topo = g.is_mem[topo]
-
-        # class mode: per-vertex class gather column (pop-order space) and
-        # the slot-provenance record plus its verification scaffolding
-        self.prov = prov
-        self.cls_topo = (np.ascontiguousarray(classes[topo])
-                         if classes is not None else None)
+        self.prov, self.level = prov, level
         if prov is not None:
             self.prov_ok, self.t_chk, self.need_chk = \
                 _prov_check_arrays(prov, m)
         else:
-            self.prov_ok = True
-            self.t_chk = self.need_chk = None
+            self.prov_ok, self.t_chk, self.need_chk = True, None, None
 
-        # queue predecessors point at the zero sentinel row n when absent
-        # (a slot that is free at t=0)
+    def schedule(self) -> tuple:
+        """The block's schedule as ``_build_plan`` takes it."""
+        return (self.g, self.m, self.cs, self.topo, self.O_mem, self.O_alu,
+                self.prov, self.level)
+
+
+class _Plan:
+    """Recorded schedules of one or more blocks, ready for one batched
+    replay: the order-augmented graphs stacked block-diagonally in
+    pop-order row space as one ``backend.LevelCSR`` (plus the shared zero
+    sentinel row ``n`` every slot chain bottoms out on), the memory rows
+    and, in class mode, their latency classes (``cls_mem``), and the
+    block boundaries ``seg_ptr``.  A single trace's plan is one block; a
+    suite's union plan spans its (member, m, compute_slots) blocks."""
+
+    __slots__ = ("n", "blocks", "lv", "mem_rows", "cls_mem", "seg_ptr")
+
+    def __init__(self, n: int, blocks: list, lv, mem_rows, cls_mem,
+                 seg_ptr):
+        self.n, self.blocks, self.lv = n, blocks, lv
+        self.mem_rows, self.cls_mem, self.seg_ptr = mem_rows, cls_mem, seg_ptr
+
+    def replay(self, alphas: np.ndarray, unit: float,
+               policy: Optional[ExecPolicy] = None):
+        """Evaluate every block at every point at once: returns finish
+        times F and ready times R, both (n+1, k) in pop-order row space.
+        The pass runs through ``ExecPolicy.accumulate`` under the
+        policy's backend / replay dtype (x64 on device / error-bounded
+        f32 with per-column demotion / numpy f64), so the returned
+        matrices are always bit-identical to the float64 numpy kernel.
+
+        ``alphas`` is (k,) scalar latencies or, on a class-mode plan,
+        (k, n_classes) class-vector rows: each memory row then gathers
+        its own class's alpha — one more gather, same stacked kernel."""
+        pol = ExecPolicy.resolve(policy=policy)
+        k = len(alphas)
+        with span("fill", rows=self.n + 1, columns=k):
+            F = np.empty((self.n + 1, k))
+            F.fill(unit)
+            F[self.mem_rows] = (alphas if self.cls_mem is None
+                                else alphas.T[self.cls_mem])
+            F[-1] = 0.0
+            R = np.zeros_like(F)
+            quanta = _bk.column_quanta(alphas, unit)
+        pol.accumulate(self.lv, F, quanta, clamp=False, R_out=R)
+        return F, R
+
+    def makespans(self, F: np.ndarray) -> np.ndarray:
+        """Per-block makespans (B, k) of a replayed F: each block's row
+        maximum, by one segmented reduction — a plain column maximum when
+        the plan is one block (the same values, at a lower cost)."""
+        if len(self.blocks) == 1:
+            return F[:-1].max(axis=0)[None]
+        return _bk.segment_max_rows(F[:-1], self.seg_ptr)
+
+    def array_nbytes(self) -> dict:
+        """Byte sizes of the plan's live arrays, keyed by name (summed
+        over blocks).
+
+        A recorded plan is part of the pipeline's theoretical working
+        set — the augmented-graph partition it holds is the same order
+        of size as the trace's own CSR — so the scale benchmark adds
+        these to ``EDag.array_nbytes`` when bounding peak RSS."""
+        lv = self.lv
+        arrs = [("mem_rows", self.mem_rows), ("cls_mem", self.cls_mem),
+                ("seg_ptr", self.seg_ptr)]
+        arrs += [(name, getattr(lv, name)) for name in (
+            "esrc", "run_dst", "run_starts", "run_lens", "run_ptr",
+            "elevel_ptr", "qpred", "qonly_ptr", "qonly_dst")]
+        arrs += [(name, getattr(b, name)) for b in self.blocks
+                 for name in ("topo", "rank", "O_mem", "O_alu", "Om_rel",
+                              "Oa_rel", "level", "prov", "t_chk",
+                              "need_chk")]
+        out: dict = {}
+        for name, a in arrs:
+            if a is not None:
+                out[name] = out.get(name, 0) + int(np.asarray(a).nbytes)
+        return out
+
+
+def _build_plan(scheds, n_classes: Optional[int] = None) -> _Plan:
+    """Turn block schedules ``(g, m, cs, topo, O_mem, O_alu, prov|None,
+    level|None)`` into one replay plan.
+
+    Each block is relabeled into its recorded pop order ``topo`` (a
+    linear extension of the augmented graph: slot chains strictly
+    increase finish times); its slot chains — the homogeneous
+    ``O[j-m] -> O[j]``, or the recorded provenance in class mode
+    (``n_classes`` set) — join its DAG edges, and a persisted ``level``
+    is validated against those edges and recomputed if it does not
+    respect them, so a corrupt cache entry degrades to a fresh
+    ``levelize``, never to a wrong evaluation order.  The blocks then
+    stack block-diagonally: edges and chains are offset with their
+    block, so chains never cross a boundary (each trace owns its slots
+    per machine configuration, exactly as if simulated alone), missing
+    queue predecessors point at the shared sentinel row, and the
+    per-block levels concatenate unchanged (blocks are disconnected).
+    One ``build_level_partition`` call yields the plan's ``LevelCSR``,
+    whose serial depth is the deepest block's, not the sum over blocks.
+    A one-block plan takes its block's arrays as they are."""
+    blocks, parts = [], []
+    off = 0
+    for g, m, cs, topo, O_mem, O_alu, prov, level in scheds:
+        n = g.n_vertices
+        rank = np.empty(n, dtype=np.int32)
+        rank[topo] = np.arange(n, dtype=np.int32)
         if prov is not None:
             qpred = _prov_qpred(rank, O_mem, O_alu, prov, m, cs, n)
         else:
             qpred = _slot_qpred(rank, O_mem, O_alu, m, cs, n)
         src_r, dst_r = rank[g.src], rank[g.dst]
-
         qdst = np.nonzero(qpred < n)[0].astype(np.int32)
         asrc = np.concatenate([src_r, qpred[qdst]])
         adst = np.concatenate([dst_r, qdst])
@@ -466,64 +548,31 @@ class _ReplayPlan:
         if level is None:
             level = _bk.levelize(asrc, adst, n)
         del asrc, adst                # only levelize needs the augmented list
-        self.level_aug = level
-        lv = _bk.build_level_partition(src_r, dst_r, level, n)
-        _attach_queue_partition(lv, dst_r, qpred, level)
-        self.lv = lv
-
-    def replay(self, alphas: np.ndarray, unit: float,
-               policy: Optional[ExecPolicy] = None):
-        """Evaluate all points at once: returns finish times F and ready
-        times R, both (n+1, k) in pop-order (topo) vertex space (the last
-        row is the zero sentinel the slot chains bottom out on).  The
-        pass runs through ``ExecPolicy.accumulate`` under the policy's
-        backend / replay dtype (x64 on device / error-bounded f32 with
-        per-column demotion / numpy f64), so the returned matrices are
-        always bit-identical to the float64 numpy kernel.
-
-        ``alphas`` may be 2-D ``(k, n_classes)`` on a class-mode plan:
-        each memory vertex then gathers its own class's alpha — one more
-        gather, same stacked kernel."""
-        pol = ExecPolicy.resolve(policy=policy)
-        k = len(alphas)
-        with span("fill", rows=self.n + 1, columns=k):
-            F = np.empty((self.n + 1, k))
-            if alphas.ndim == 2:
-                F[:-1] = np.where(self.is_mem_topo[:, None],
-                                  alphas.T[self.cls_topo], unit)
-            else:
-                F[:-1] = np.where(self.is_mem_topo[:, None],
-                                  alphas[None, :], unit)
-            F[-1] = 0.0
-            R = np.zeros_like(F)
-            quanta = _bk.column_quanta(alphas, unit)
-        pol.accumulate(self.lv, F, quanta, clamp=False, R_out=R)
-        return F, R
-
-    def array_nbytes(self) -> dict:
-        """Byte sizes of the plan's live arrays, keyed by name.
-
-        A recorded plan is part of the pipeline's theoretical working
-        set — the augmented-graph partition it holds is the same order
-        of size as the trace's own CSR — so the scale benchmark adds
-        these to ``EDag.array_nbytes`` when bounding peak RSS."""
-        lv = self.lv
-        arrs = dict(topo=self.topo, rank=self.rank, O_mem=self.O_mem,
-                    O_alu=self.O_alu, Om_rel=self.Om_rel,
-                    Oa_rel=self.Oa_rel, is_mem_topo=self.is_mem_topo,
-                    level_aug=self.level_aug, esrc=lv.esrc,
-                    run_dst=lv.run_dst, run_starts=lv.run_starts,
-                    run_lens=lv.run_lens, run_ptr=lv.run_ptr,
-                    elevel_ptr=lv.elevel_ptr)
-        for name in ("qpred", "qonly_ptr", "qonly_dst"):
-            a = getattr(lv, name, None)
-            if a is not None:
-                arrs[name] = a
-        for name in ("prov", "cls_topo", "t_chk", "need_chk"):
-            a = getattr(self, name)
-            if a is not None:
-                arrs[name] = a
-        return {k: int(np.asarray(v).nbytes) for k, v in arrs.items()}
+        blocks.append(_Block(g, m, cs, off, topo, rank, O_mem, O_alu, prov,
+                             level))
+        mem = np.flatnonzero(g.is_mem[topo])
+        cls = (g.mem_class_column(n_classes)[topo[mem]]
+               if n_classes is not None else None)
+        parts.append((src_r, dst_r, qpred, level, mem, cls))
+        off += n
+    if len(parts) == 1:
+        src, dst, qpred, level, mem_rows, cls_mem = parts[0]
+    else:
+        # block offsets: chains stay inside their block, and missing
+        # queue predecessors retarget the shared sentinel row ``off``
+        src_p, dst_p, q_p, lvl_p, mem_p, cls_p = zip(*parts)
+        offs = [b.off for b in blocks]
+        src = np.concatenate([a + o for a, o in zip(src_p, offs)])
+        dst = np.concatenate([a + o for a, o in zip(dst_p, offs)])
+        qpred = np.concatenate([np.where(q < len(q), q + o, off)
+                                for q, o in zip(q_p, offs)])
+        level = np.concatenate(lvl_p)
+        mem_rows = np.concatenate([a + o for a, o in zip(mem_p, offs)])
+        cls_mem = np.concatenate(cls_p) if n_classes is not None else None
+    lv = _bk.build_level_partition(src, dst, level, off)
+    _attach_queue_partition(lv, dst, qpred, level)
+    seg_ptr = np.array([b.off for b in blocks] + [off], dtype=np.int64)
+    return _Plan(off, blocks, lv, mem_rows, cls_mem, seg_ptr)
 
 
 def _enabler_pass(g: EDag, rank: np.ndarray, F: np.ndarray, R: np.ndarray,
@@ -587,7 +636,7 @@ def _verify_class(g: EDag, rank: np.ndarray, F: np.ndarray, R: np.ndarray,
     return pair_ok.all(axis=0)
 
 
-def _verify_slots(plan: _ReplayPlan, F: np.ndarray) -> np.ndarray:
+def _verify_slots(blk: _Block, F: np.ndarray) -> np.ndarray:
     """Check per point that the recorded slot provenance is a greedy
     replace-min execution for this point's finish times (class mode).
 
@@ -606,40 +655,85 @@ def _verify_slots(plan: _ReplayPlan, F: np.ndarray) -> np.ndarray:
     check this makes class-mode replay arithmetic bit-identical to
     ``_event_loop_classes`` (same IEEE max/add per vertex)."""
     k = F.shape[1]
-    W = len(plan.O_mem)
+    W = len(blk.O_mem)
     if W == 0:
         return np.ones(k, dtype=bool)
-    if not plan.prov_ok:
+    if not blk.prov_ok:
         return np.zeros(k, dtype=bool)
-    Fo = F[plan.Om_rel]                      # (W, k), issue order
+    Fo = F[blk.Om_rel]                       # (W, k), issue order
     Vo = np.zeros_like(Fo)
-    has = plan.prov >= 0
-    Vo[has] = Fo[plan.prov[has]]
+    has = blk.prov >= 0
+    Vo[has] = Fo[blk.prov[has]]
     ok = (np.diff(Vo, axis=0) >= 0).all(axis=0) if W > 1 \
         else np.ones(k, dtype=bool)
-    nc = plan.need_chk
+    nc = blk.need_chk
     if len(nc):
-        ok &= (Fo[nc] >= Vo[plan.t_chk[nc]]).all(axis=0)
+        ok &= (Fo[nc] >= Vo[blk.t_chk[nc]]).all(axis=0)
     return ok
 
 
-def _points_chunk(n: int, k: int, mem_budget: Optional[int] = None) -> int:
-    """Balanced point chunk under the replay memory budget — legacy
-    wrapper over ``ExecPolicy.points_chunk`` for callers holding a raw
-    byte budget instead of a policy."""
-    return ExecPolicy.resolve(mem_budget=mem_budget).points_chunk(n, k)
+def _replay_verify(plan: _Plan, alphas: np.ndarray, unit: float,
+                   pol: ExecPolicy):
+    """Replay ``alphas`` through every block of ``plan``, in chunks under
+    the policy's memory budget, verify each block per point, and reduce.
+
+    Returns per-block makespans and the certified mask, both (B, P).  A
+    point is certified for a block when the block's recorded issue
+    orders still sort by ``(R, E, vid)`` and, in class mode, its slot
+    provenance is still a greedy execution; only certified makespans
+    are results — the callers re-run the rest."""
+    B, P = len(plan.blocks), len(alphas)
+    mk = np.zeros((B, P))
+    ok = np.zeros((B, P), dtype=bool)
+    chunk = pol.points_chunk(plan.n, P)
+    for c0 in range(0, P, chunk):
+        cols = slice(c0, c0 + chunk)
+        F, R = plan.replay(alphas[cols], unit, policy=pol)
+        for b, blk in enumerate(plan.blocks):
+            rows = slice(blk.off, blk.off + blk.g.n_vertices)
+            Fv, Rv = F[rows], R[rows]
+            with span("verify"):
+                okc = _verify_class(blk.g, blk.rank, Fv, Rv, blk.O_mem,
+                                    blk.Om_rel)
+                if blk.prov is not None:
+                    okc &= _verify_slots(blk, Fv)
+                if blk.cs:
+                    okc &= _verify_class(blk.g, blk.rank, Fv, Rv, blk.O_alu,
+                                         blk.Oa_rel)
+            ok[b, cols] = okc
+        with span("reduce"):
+            mk[:, cols] = plan.makespans(F)
+    return mk, ok
 
 
 # ----------------------------------------------------------- schedule reuse
 
-def _memo_plan(g: EDag, key, plan: _ReplayPlan) -> None:
-    memo = getattr(g, "_replay_plans", None)
+def _memo_get(memo, key):
+    """The entry of an LRU memo (an ``OrderedDict``), or None."""
+    plan = memo.get(key) if memo is not None else None
+    if plan is not None:
+        memo.move_to_end(key)
+    return plan
+
+
+def _memo_put(memo, key, plan, cap: int) -> None:
+    """Enter ``plan`` into an LRU memo of at most ``cap`` entries."""
     if memo is None:
         return
     memo[key] = plan
     memo.move_to_end(key)
-    while len(memo) > _PLAN_MEMO_CAP:
+    while len(memo) > cap:
         memo.popitem(last=False)
+
+
+def _memo_key(g: EDag, m: int, cs: int, unit: float,
+              n_classes: Optional[int]) -> tuple:
+    """A trace's plan-memo key: ``(m, cs, unit)``, and in class mode the
+    class overlay's digest besides (the overlay is not part of the trace
+    digest)."""
+    if n_classes is None:
+        return (m, cs, float(unit))
+    return ("classes", m, cs, float(unit), g.mem_class_digest())
 
 
 def _validate_schedule(g: EDag, m: int, cs: int, topo, O_mem,
@@ -694,58 +788,78 @@ def _validate_schedule(g: EDag, m: int, cs: int, topo, O_mem,
     return rank
 
 
-def _plan_from_cache(g: EDag, m: int, cs: int, topo, O_mem, O_alu,
-                     level) -> Optional[_ReplayPlan]:
-    """Rebuild a replay plan from persisted arrays, or None if they fail
-    ``_validate_schedule``."""
-    if _validate_schedule(g, m, cs, topo, O_mem, O_alu) is None:
-        return None
-    return _ReplayPlan(g, topo, O_mem, O_alu, m, cs, level=level)
+def _schedule(g: EDag, m: int, cs: int, unit: float, a0, lookup: bool,
+              n_classes: Optional[int] = None):
+    """One block schedule of ``g`` under ``(m, cs)``: the trace's plan
+    memo, then the disk, then one instrumented recording at ``a0`` (a
+    scalar alpha, or a class-vector row when ``n_classes`` is set).
+    ``lookup`` False goes straight to the recording.
 
+    The disk tier (``schedule_cache``, keyed by the trace digest) holds
+    scalar schedules of traces at or above its size floor only: its
+    format carries no provenance field.  A loaded entry must pass
+    ``_validate_schedule``.  The recording is the span
+    ``edan.schedule.record``, the serial cost a warm cache amortizes,
+    which ``schedule_cache.stats["record_runs"]`` counts.
 
-def _get_plan(g: EDag, m: int, cs: int,
-              unit: float) -> Optional[_ReplayPlan]:
-    """Look up a reusable replay plan: per-process memo, then disk."""
-    key = (m, cs, float(unit))
-    memo = getattr(g, "_replay_plans", None)
-    hit = memo is not None and key in memo
-    with span("plan", hit=int(hit)):
-        if hit:
-            memo.move_to_end(key)
+    Returns ``(sched, plan, mk0)``: the schedule as ``_build_plan``
+    takes it, the memoized one-block plan it came from on a memo hit
+    (else None), and the master makespan of a fresh recording (else
+    None)."""
+    n = g.n_vertices
+    if lookup:
+        plan = _memo_get(getattr(g, "_replay_plans", None),
+                         _memo_key(g, m, cs, unit, n_classes))
+        if plan is not None:
             _sc.stats.add("memory_hits")
-            return memo[key]
-        if g.n_vertices >= _sc.min_vertices():
+            return plan.blocks[0].schedule(), plan, None
+        if n_classes is None and n >= _sc.min_vertices():
             with span("schedule.load") as sp:
-                got = _sc.load(g.trace_digest(), m, cs, g.n_vertices, unit)
-                plan = (_plan_from_cache(g, m, cs, *got)
-                        if got is not None else None)
-                sp.set_metadata(hit=int(plan is not None))
-            if plan is not None:
+                got = _sc.load(g.trace_digest(), m, cs, n, unit)
+                hit = got is not None and _validate_schedule(
+                    g, m, cs, *got[:3]) is not None
+                sp.set_metadata(hit=int(hit))
+            if hit:
                 _sc.stats.add("disk_hits")
-                _memo_plan(g, key, plan)
-                return plan
+                topo, O_mem, O_alu, level = got
+                return (g, m, cs, topo, O_mem, O_alu, None, level), None, None
         _sc.stats.add("misses")
-        return None
-
-
-def _record_plan(g: EDag, sim_lists, m: int, cs: int, a0: float,
-                 unit: float, persist: bool):
-    """One instrumented reference run -> (master makespan, replay plan);
-    the plan is memoized and, for large traces, persisted to disk.  The
-    serial recording (event loop + plan build) is the span
-    ``edan.schedule.record``: the cost a warm cache amortizes, which
-    ``schedule_cache.stats["record_runs"]`` counts."""
     _sc.stats.add("record_runs")
-    with span("schedule.record", vertices=g.n_vertices):
-        mk0, topo, O_mem, O_alu = _event_loop(
-            g.is_mem, sim_lists, m, a0, unit, cs, record=True)
-        plan = _ReplayPlan(g, topo, O_mem, O_alu, m, cs)
-    if persist:
-        _memo_plan(g, (m, cs, float(unit)), plan)
-        if g.n_vertices >= _sc.min_vertices():
-            _sc.store(g.trace_digest(), m, cs, g.n_vertices, unit,
-                      topo, O_mem, O_alu, plan.level_aug)
-    return mk0, plan
+    with span("schedule.record", vertices=n):
+        if n_classes is None:
+            mk0, topo, O_mem, O_alu = _event_loop(
+                g.is_mem, g._sim_lists(), m, float(a0), unit, cs, record=True)
+            prov = None
+        else:
+            mk0, topo, O_mem, O_alu, prov = _event_loop_classes(
+                g.is_mem, g._sim_lists(), m, a0, g.mem_class_column(n_classes),
+                unit, cs, record=True)
+    return (g, m, cs, topo, O_mem, O_alu, prov, None), None, mk0
+
+
+def _keep(blk: _Block, unit: float, n_classes: Optional[int],
+          plan: Optional[_Plan] = None, store: bool = True) -> None:
+    """Make a block's schedule reusable by later lookups of its trace.
+
+    ``store`` writes it to disk where the disk tier takes it (a scalar
+    schedule of a trace at or above the floor).  The trace's plan memo
+    gets ``plan`` when the caller holds the block's one-block plan; a
+    block of a union gets a plan of its own only where the disk did not
+    take it (persistence disabled, a small trace, or class mode), so
+    that a suite's recordings warm later single-trace sweeps at every
+    size."""
+    g = blk.g
+    stored = (store and n_classes is None and
+              g.n_vertices >= _sc.min_vertices() and
+              _sc.store(g.trace_digest(), blk.m, blk.cs, g.n_vertices, unit,
+                        blk.topo, blk.O_mem, blk.O_alu, blk.level))
+    if plan is None:
+        if stored:
+            return
+        plan = _build_plan([blk.schedule()], n_classes)
+    _memo_put(getattr(g, "_replay_plans", None),
+              _memo_key(g, blk.m, blk.cs, unit, n_classes), plan,
+              _PLAN_MEMO_CAP)
 
 
 def _reference_points(g: EDag, spec: SweepSpec, m: int,
@@ -768,130 +882,50 @@ def _reference_points(g: EDag, spec: SweepSpec, m: int,
 
 def _batch_uniq(g: EDag, alphas: np.ndarray, m: int, cs: int, unit: float,
                 pol: ExecPolicy) -> np.ndarray:
-    """The scalar batched engine over a sorted-unique, finite-positive
-    alpha axis: record → chunked replay → verify → re-record stragglers.
-    ``SweepSpec`` guarantees the axis shape; callers restore caller
-    order from the spec."""
-    P = len(alphas)
-    out = np.zeros(P)
-    n = g.n_vertices
-    sim_lists = g._sim_lists()
-    remaining = np.arange(P)
-    plan = _get_plan(g, m, cs, unit) if pol.use_cache else None
-    mk0: Optional[float] = None       # master makespan; None for reused plans
-    persist = pol.use_cache and plan is None
+    """The batched engine over a sorted-unique, finite-positive alpha
+    axis of scalars, or of class-vector rows (2-D): record → chunked
+    replay → verify → re-record stragglers.  ``SweepSpec`` guarantees the
+    axis shape; callers restore caller order from the spec.
+
+    Class mode differs only in its recorder (``_event_loop_classes``:
+    slot provenance instead of the homogeneous chain), the extra
+    ``_verify_slots`` certificate, and its schedule reuse: in-process
+    only, keyed by the class overlay's digest (``_schedule``)."""
+    n_classes = alphas.shape[1] if alphas.ndim == 2 else None
+    if n_classes is not None:
+        g.mem_class_column(n_classes)     # raises if the overlay misfits
+    out = np.zeros(len(alphas))
+    remaining = np.arange(len(alphas))
+    lookup = persist = pol.use_cache
     while remaining.size:
-        reused = plan is not None and mk0 is None
-        if plan is None:
-            a0 = float(alphas[remaining[0]])
-            with span("plan", hit=0):
-                mk0, plan = _record_plan(g, sim_lists, m, cs, a0, unit,
-                                         persist=persist)
-            # only the sweep's first recording is worth keeping: later
-            # ones are per-point fallbacks for tie-shifted orders and
-            # would thrash the cache with alpha-specific schedules
-            persist = False
-        ok = np.zeros(remaining.size, dtype=bool)
-        chunk = pol.points_chunk(n, remaining.size)
-        for c0 in range(0, remaining.size, chunk):
-            sel = remaining[c0:c0 + chunk]
-            F, R = plan.replay(alphas[sel], unit, policy=pol)
-            with span("verify"):
-                okc = _verify_class(g, plan.rank, F, R, plan.O_mem,
-                                    plan.Om_rel)
-                if cs:
-                    okc &= _verify_class(g, plan.rank, F, R, plan.O_alu,
-                                         plan.Oa_rel)
-            with span("reduce"):
-                mk = F.max(axis=0)
-                out[sel[okc]] = mk[okc]
-            ok[c0:c0 + chunk] = okc
+        with span("plan") as sp:
+            sched, plan, mk0 = _schedule(g, m, cs, unit, alphas[remaining[0]],
+                                         lookup, n_classes)
+            sp.set_metadata(hit=int(plan is not None))
+            if plan is None:
+                plan = _build_plan([sched], n_classes)
+                if persist:
+                    _keep(plan.blocks[0], unit, n_classes, plan,
+                          store=mk0 is not None)
+        mk, ok = _replay_verify(plan, alphas[remaining], unit, pol)
+        mk, ok = mk[0], ok[0]
+        out[remaining[ok]] = mk[ok]
         if not ok[0] and mk0 is not None:
             # the master's own schedule always certifies; if the check ever
             # disagrees, trust its recorded makespan and keep making progress
             out[remaining[0]] = mk0
             ok[0] = True
-        if reused and not ok.all():
-            # the reused plan failed part of this sweep — let the next
-            # fresh recording replace it (memo + disk), so repeated
-            # sweeps converge on a schedule that certifies their points
-            # instead of re-paying the serial recording forever
-            persist = pol.use_cache
+        # only the sweep's first recording is worth keeping: later ones
+        # are per-point fallbacks for tie-shifted orders and would thrash
+        # the cache with alpha-specific schedules.  But when a reused plan
+        # failed part of this sweep, the next fresh recording replaces it
+        # (memo + disk), so repeated sweeps converge on a schedule that
+        # certifies their points instead of re-paying the recording forever
+        persist = pol.use_cache and mk0 is None and not ok.all()
+        # anything left re-records from a fresh master on the next
+        # iteration (guaranteed progress from then on)
+        lookup = False
         remaining = remaining[~ok]
-        # anything a reused plan failed to certify re-records from a fresh
-        # master on the next iteration (guaranteed progress from then on)
-        plan, mk0 = None, None
-    return out
-
-
-def _batch_uniq_classes(g: EDag, alphas: np.ndarray, m: int, cs: int,
-                        unit: float, pol: ExecPolicy) -> np.ndarray:
-    """Class-mode batched engine over lexsorted-unique class-vector rows:
-    one recorded provenance schedule, stacked class-vector replay,
-    per-point order + slot verification.
-
-    Mirrors the scalar engine's structure (record → chunked replay →
-    verify → re-record stragglers) with two differences: the recording
-    runs ``_event_loop_classes`` (slot provenance instead of the
-    homogeneous chain) and plans are memoized in-process only, keyed by
-    the class overlay's digest — the on-disk schedule format carries no
-    provenance field, and the overlay is not part of the trace digest."""
-    P = len(alphas)
-    out = np.zeros(P)
-    n = g.n_vertices
-    cls = g.mem_class_column(alphas.shape[1])
-    sim_lists = g._sim_lists()
-    remaining = np.arange(P)
-    key = ("classes", m, cs, float(unit), g.mem_class_digest())
-    plan = None
-    memo = getattr(g, "_replay_plans", None)
-    if pol.use_cache:
-        hit = memo is not None and key in memo
-        with span("plan", hit=int(hit)):
-            if hit:
-                memo.move_to_end(key)
-                _sc.stats.add("memory_hits")
-                plan = memo[key]
-    mk0: Optional[float] = None
-    persist = pol.use_cache and plan is None
-    while remaining.size:
-        reused = plan is not None and mk0 is None
-        if plan is None:
-            _sc.stats.add("record_runs")
-            with span("plan", hit=0), span("schedule.record", vertices=n):
-                mk0, topo, O_mem, O_alu, prov = _event_loop_classes(
-                    g.is_mem, sim_lists, m, alphas[remaining[0]], cls, unit,
-                    cs, record=True)
-                plan = _ReplayPlan(g, topo, O_mem, O_alu, m, cs,
-                                   prov=prov, classes=cls)
-            if persist:
-                _memo_plan(g, key, plan)
-            persist = False
-        ok = np.zeros(remaining.size, dtype=bool)
-        chunk = pol.points_chunk(n, remaining.size)
-        for c0 in range(0, remaining.size, chunk):
-            sel = remaining[c0:c0 + chunk]
-            F, R = plan.replay(alphas[sel], unit, policy=pol)
-            with span("verify"):
-                okc = _verify_class(g, plan.rank, F, R, plan.O_mem,
-                                    plan.Om_rel)
-                okc &= _verify_slots(plan, F)
-                if cs:
-                    okc &= _verify_class(g, plan.rank, F, R, plan.O_alu,
-                                         plan.Oa_rel)
-            with span("reduce"):
-                mk = F.max(axis=0)
-                out[sel[okc]] = mk[okc]
-            ok[c0:c0 + chunk] = okc
-        if not ok[0] and mk0 is not None:
-            # the master's own schedule always certifies; if the check
-            # ever disagrees, trust its recorded makespan and progress
-            out[remaining[0]] = mk0
-            ok[0] = True
-        if reused and not ok.all():
-            persist = pol.use_cache
-        remaining = remaining[~ok]
-        plan, mk0 = None, None
     return out
 
 
@@ -904,11 +938,7 @@ def _batch_for_pair(g: EDag, spec: SweepSpec, m: int, cs: int,
         return np.zeros(spec.n_points)
     if spec.degenerate(m):
         return _reference_points(g, spec, m, cs)
-    if spec.class_mode:
-        res = _batch_uniq_classes(g, spec.uniq, m, cs, spec.unit, pol)
-    else:
-        res = _batch_uniq(g, spec.uniq, m, cs, spec.unit, pol)
-    return spec.restore(res)
+    return spec.restore(_batch_uniq(g, spec.uniq, m, cs, spec.unit, pol))
 
 
 def simulate_batch(g: EDag, alphas, m: int = 4, unit: float = 1.0,

@@ -14,13 +14,14 @@ the full alpha × m × compute_slots grid for *every member at once*:
   from the usual reuse tiers — the member's in-process plan memo, then
   the persistent ``schedule_cache`` keyed by that member's
   ``trace_digest()`` — and only missing combinations pay the serial
-  recording run.  The schedules are then concatenated in rank space:
-  slot chains are offset with their block, so they never cross a block
-  boundary (each trace owns its own m memory slots and ``compute_slots``
-  ALU slots per machine configuration, exactly as if simulated alone),
-  and the union's augmented levels are the per-block levels unchanged —
-  a block-diagonal graph levelizes blockwise.  One
-  ``build_level_partition`` call produces the union ``LevelCSR``.
+  recording run.  The simulator's one plan builder
+  (``scheduler._build_plan``; a single trace's plan is one block) then
+  stacks the schedules in rank space: slot chains are offset with their
+  block, so they never cross a block boundary (each trace owns its own
+  m memory slots and ``compute_slots`` ALU slots per machine
+  configuration, exactly as if simulated alone), and the union's
+  augmented levels are the per-block levels unchanged — a
+  block-diagonal graph levelizes blockwise.
 
 * **One stacked (max,+) replay for the whole grid.**  Levels of
   independent blocks *interleave*: the shared numpy/jax level kernel
@@ -41,23 +42,23 @@ the full alpha × m × compute_slots grid for *every member at once*:
 
 * **Bit-exactness is per member, unconditional.**  The per-point
   ``(R, E, vid)`` issue-order verification runs on each member's block
-  rows exactly as in the single-trace engine; any (member, point) the
-  union schedule fails to certify falls back to that member's own
-  ``simulate_batch`` (which re-records and, with ``use_cache``, persists
-  the replacement).  Every entry of the suite grid is therefore
-  bit-identical to single-trace ``sweep_grid`` — property-tested in
-  ``tests/test_suite.py`` and asserted per trace in the suite benchmark.
+  rows in the same loop as the single-trace engine's
+  (``scheduler._replay_verify``); any (member, point) the union schedule
+  fails to certify falls back to that member's own ``simulate_batch``
+  (which re-records and, with ``use_cache``, persists the replacement).
+  Every entry of the suite grid is therefore bit-identical to
+  single-trace ``sweep_grid`` — property-tested in ``tests/test_suite.py``
+  and asserted per trace in the suite benchmark.
 
 * **Class-vector grids ride the same union.**  A 2-D alpha matrix of
   latency-class vectors builds the plan from class-mode block schedules:
-  each block records slot *provenance* (``_event_loop_classes``) instead
-  of the homogeneous slot chain, provenance edges are offset with their
-  block exactly like slot chains, and the union F fill gathers each
-  memory row's own class alpha through the plan's ``cls_mem`` column.
-  Verification adds the per-block ``_verify_slots`` provenance
-  certificate, so class grids run as one stacked level pass per distinct
-  m — same chunking, same budget accounting, same fallback — instead of
-  a per-member Python loop.
+  each block records slot *provenance* instead of the homogeneous slot
+  chain, provenance edges are offset with their block exactly like slot
+  chains, and the union F fill gathers each memory row's own class
+  alpha.  Verification adds the per-block slot-provenance certificate,
+  so class grids run as one stacked level pass per distinct m — same
+  chunking, same budget accounting, same fallback — instead of a
+  per-member Python loop.
 
 Sweep queries arrive normalized as one ``plan.SweepSpec`` (alphas
 deduped/sorted once, caller order restored at the end) and execution
@@ -77,16 +78,12 @@ from typing import Optional, Sequence
 import numpy as np
 
 from . import backend as _bk
-from . import schedule_cache as _sc
 from .counters import span
 from .graph import EDag, _auto_sweep_chunk, concat_edags
 from .plan import ExecPolicy, SweepSpec
-from .scheduler import (_ReplayPlan, _aug_level_valid,
-                        _attach_queue_partition, _event_loop,
-                        _event_loop_classes, _memo_plan,
-                        _prov_check_arrays, _prov_qpred, _slot_qpred,
-                        _sweep_grid_spec, _validate_schedule,
-                        _verify_class, _verify_slots, simulate_batch)
+from .scheduler import (_build_plan, _keep, _memo_get, _memo_put,
+                        _replay_verify, _schedule, _sweep_grid_spec,
+                        simulate_batch)
 
 # Per-suite union-plan memo, keyed by (member group, pairs tuple, unit):
 # one entry per replay group per distinct-m pairs subset, so a suite with
@@ -223,260 +220,7 @@ def suite_t_inf_sweep(suite: EDagSuite, alphas, unit: float = 1.0,
     return np.concatenate(out, axis=1)
 
 
-# ------------------------------------------------------------ the suite plan
-
-class _BlockSched:
-    """One (member, m, compute_slots) block of a union replay plan:
-    everything the per-point (R, E, vid) verification and the fallback
-    path need, in member-local rank space (F/R block views index with
-    these directly), plus where the block's results land in the grid.
-
-    On class-mode plans the block also carries the recorded slot
-    provenance and its verification scaffolding — the same attribute
-    names ``_verify_slots`` reads off a single-trace ``_ReplayPlan``, so
-    the identical certifier runs on the block's F view."""
-
-    __slots__ = ("g", "trace", "pair", "m", "cs", "off", "rank",
-                 "O_mem", "Om_rel", "O_alu", "Oa_rel",
-                 "prov", "prov_ok", "t_chk", "need_chk")
-
-    def __init__(self, g: EDag, trace: int, pair: int, m: int, cs: int,
-                 off: int, rank, O_mem, O_alu, prov=None):
-        self.g = g
-        self.trace, self.pair = trace, pair
-        self.m, self.cs, self.off = m, cs, off
-        self.rank = rank
-        self.O_mem, self.O_alu = O_mem, O_alu
-        self.Om_rel = rank[O_mem]
-        self.Oa_rel = rank[O_alu] if cs else np.zeros(0, dtype=np.int64)
-        self.prov = prov
-        if prov is not None:
-            self.prov_ok, self.t_chk, self.need_chk = \
-                _prov_check_arrays(prov, m)
-        else:
-            self.prov_ok = True
-            self.t_chk = self.need_chk = None
-
-
-class _SuitePlan:
-    """Union replay plan over the full (member, m, compute_slots) block
-    product: one ``LevelCSR`` for the whole grid, per-block verification
-    state, and the block boundary array (``seg_ptr``) the per-block
-    makespan reduction runs over.  ``replay`` evaluates every grid
-    configuration for every member at every sweep point of a chunk in a
-    single ``level_accumulate`` call.
-
-    ``cls_mem`` (class-mode plans only) is the per-memory-row latency
-    class, aligned with ``mem_rows``: each member's ``set_mem_classes``
-    overlay gathered through its block's pop order, so a class-vector
-    chunk fills the union F matrix with one fancy-indexed gather."""
-
-    __slots__ = ("n", "lv", "mem_rows", "seg_ptr", "blocks", "cls_mem")
-
-    def __init__(self, n: int, lv, mem_rows, seg_ptr, blocks,
-                 cls_mem=None):
-        self.n = n
-        self.lv = lv
-        self.mem_rows = mem_rows
-        self.seg_ptr = seg_ptr
-        self.blocks = blocks
-        self.cls_mem = cls_mem
-
-    def replay(self, alphas: np.ndarray, unit: float,
-               pol: Optional[ExecPolicy] = None):
-        """All blocks × all points at once: finish and ready times,
-        (n_rows + 1, k) in blockwise pop-order row space (the last row is
-        the shared zero sentinel every block's slot chains bottom out
-        on).  Runs through ``ExecPolicy.accumulate`` under the policy's
-        replay dtype, so the matrices are always bit-identical to the
-        float64 numpy kernel.  ``alphas`` is (k,) scalar latencies or,
-        on a class-mode plan, (k, n_classes) class-vector rows."""
-        pol = ExecPolicy.resolve(policy=pol)
-        k = len(alphas)
-        with span("fill", rows=self.n + 1, columns=k):
-            F = np.empty((self.n + 1, k))
-            F.fill(unit)
-            if self.cls_mem is not None:
-                F[self.mem_rows] = alphas.T[self.cls_mem]
-            else:
-                F[self.mem_rows] = alphas    # rows of memory vertices
-            F[-1] = 0.0
-            R = np.zeros_like(F)
-            quanta = _bk.column_quanta(alphas, unit)
-        pol.accumulate(self.lv, F, quanta, clamp=False, R_out=R)
-        return F, R
-
-
-def _member_schedule(g: EDag, m: int, cs: int, unit: float, a0: float,
-                     use_cache: bool):
-    """One member's recorded schedule ``(topo, O_mem, O_alu, level|None,
-    fresh)`` — memo, then disk (keyed by the member's trace digest), then
-    one instrumented recording run at alpha ``a0``."""
-    n = g.n_vertices
-    if use_cache:
-        key = (m, cs, float(unit))
-        memo = getattr(g, "_replay_plans", None)
-        if memo is not None and key in memo:
-            p = memo[key]
-            memo.move_to_end(key)
-            _sc.stats.add("memory_hits")
-            return p.topo, p.O_mem, p.O_alu, p.level_aug, False
-        if n >= _sc.min_vertices():
-            with span("schedule.load") as sp:
-                got = _sc.load(g.trace_digest(), m, cs, n, unit)
-                hit = got is not None and _validate_schedule(
-                    g, m, cs, *got[:3]) is not None
-                sp.set_metadata(hit=int(hit))
-            if hit:
-                _sc.stats.add("disk_hits")
-                topo, O_mem, O_alu, level = got
-                return topo, O_mem, O_alu, level, False
-        _sc.stats.add("misses")
-    _sc.stats.add("record_runs")
-    with span("schedule.record", vertices=n):
-        _, topo, O_mem, O_alu = _event_loop(g.is_mem, g._sim_lists(), m,
-                                            a0, unit, cs, record=True)
-    return topo, O_mem, O_alu, None, True
-
-
-def _member_schedule_classes(g: EDag, m: int, cs: int, unit: float,
-                             a0, cls, use_cache: bool):
-    """Class-mode member schedule ``(topo, O_mem, O_alu, prov,
-    level|None, fresh)`` — the member's in-process plan memo (keyed by
-    the class overlay's digest, exactly as the single-trace class engine
-    keys it), then one instrumented ``_event_loop_classes`` recording at
-    class-vector row ``a0``.  There is no disk tier: the persisted
-    schedule format carries no provenance field, and the overlay is not
-    part of the trace digest."""
-    if use_cache:
-        key = ("classes", m, cs, float(unit), g.mem_class_digest())
-        memo = getattr(g, "_replay_plans", None)
-        if memo is not None and key in memo:
-            p = memo[key]
-            memo.move_to_end(key)
-            _sc.stats.add("memory_hits")
-            return p.topo, p.O_mem, p.O_alu, p.prov, p.level_aug, False
-        _sc.stats.add("misses")
-    _sc.stats.add("record_runs")
-    with span("schedule.record", vertices=g.n_vertices):
-        _, topo, O_mem, O_alu, prov = _event_loop_classes(
-            g.is_mem, g._sim_lists(), m, a0, cls, unit, cs, record=True)
-    return topo, O_mem, O_alu, prov, None, True
-
-
-def _build_suite_plan(suite: EDagSuite, pairs, unit: float, a0,
-                      use_cache: bool,
-                      member_idx: Optional[Sequence[int]] = None,
-                      n_classes: Optional[int] = None) -> _SuitePlan:
-    """Concatenate the (member, m, compute_slots) block schedules into one
-    block-diagonal replay plan for the whole grid: slot chains and DAG
-    edges are offset with their block, per-block augmented levels
-    concatenate unchanged (blocks are disconnected), and a single
-    ``build_level_partition`` call produces the union ``LevelCSR``.  The
-    serial depth of the resulting replay is the *deepest block*, not the
-    sum over members and machine pairs.  ``member_idx`` restricts the
-    plan to a subset of members (a replay *group* — see
-    ``_member_groups``); block ``trace`` ids stay global, so results
-    scatter into the full suite grid unchanged.
-
-    ``n_classes`` switches the plan to class mode: ``a0`` is then the
-    master class-vector row, block schedules come from
-    ``_member_schedule_classes`` (slot provenance instead of homogeneous
-    chains, wired through ``_prov_qpred`` with block offsets), and the
-    plan carries the per-memory-row class gather column ``cls_mem``."""
-    if member_idx is None:
-        member_idx = range(suite.n_traces)
-    classes = n_classes is not None
-    n_rows = sum(suite.members[k].n_vertices
-                 for k in member_idx) * len(pairs)
-    qpred_u = np.full(n_rows, n_rows, dtype=np.int64)
-    is_mem_rows = np.zeros(n_rows, dtype=bool)
-    cls_rows = np.zeros(n_rows, dtype=np.int64) if classes else None
-    src_parts, dst_parts, lvl_parts = [], [], []
-    blocks: list = []
-    seg_ptr = [0]
-    off = 0
-    for pair, (m, cs) in enumerate(pairs):
-        for k in member_idx:
-            g = suite.members[k]
-            n = g.n_vertices
-            seg_ptr.append(off + n)
-            if n == 0:
-                blocks.append(None)
-                continue
-            if classes:
-                cls_col = g.mem_class_column(n_classes)
-                topo, O_mem, O_alu, prov, level, fresh = \
-                    _member_schedule_classes(g, m, cs, unit, a0, cls_col,
-                                             use_cache)
-            else:
-                cls_col = prov = None
-                topo, O_mem, O_alu, level, fresh = _member_schedule(
-                    g, m, cs, unit, a0, use_cache)
-            rank = np.empty(n, dtype=np.int64)
-            rank[topo] = np.arange(n)
-            if classes:
-                qpred = _prov_qpred(rank, O_mem, O_alu, prov, m, cs, n)
-            else:
-                qpred = _slot_qpred(rank, O_mem, O_alu, m, cs, n)
-            src_r, dst_r = rank[g.src], rank[g.dst]
-            qdst = np.nonzero(qpred < n)[0]
-            asrc = np.concatenate([src_r, qpred[qdst]])
-            adst = np.concatenate([dst_r, qdst])
-            if level is not None:
-                level = np.asarray(level)
-                if not _aug_level_valid(level, asrc, adst, n):
-                    level = None      # invalid persisted levels: recompute
-            if level is None:
-                level = _bk.levelize(asrc, adst, n)
-            if fresh and use_cache:
-                persisted = not classes and n >= _sc.min_vertices() and \
-                    _sc.store(g.trace_digest(), m, cs, n, unit, topo,
-                              O_mem, O_alu, level)
-                if not persisted:
-                    # below the disk floor (or persistence disabled, or
-                    # class mode — which has no disk format) the member
-                    # memo is the only tier that can make this recording
-                    # reusable — "suite warms singles" must hold there
-                    # too, so pay the one member plan build
-                    mkey = (("classes", m, cs, float(unit),
-                             g.mem_class_digest()) if classes
-                            else (m, cs, float(unit)))
-                    _memo_plan(g, mkey,
-                               _ReplayPlan(g, topo, O_mem, O_alu, m, cs,
-                                           level=level, prov=prov,
-                                           classes=cls_col))
-            # block offsets: slot chains stay inside their block, missing
-            # predecessors retarget the shared sentinel row n_rows
-            qpred_u[off:off + n] = np.where(qpred < n, qpred + off, n_rows)
-            src_parts.append(src_r + off)
-            dst_parts.append(dst_r + off)
-            lvl_parts.append(level)
-            is_mem_rows[off:off + n] = g.is_mem[topo]
-            if classes:
-                cls_rows[off:off + n] = cls_col[topo]
-            blocks.append(_BlockSched(g, k, pair, m, cs, off, rank,
-                                      O_mem, O_alu, prov=prov))
-            off += n
-    empty = np.zeros(0, dtype=np.int64)
-    src_u = np.concatenate(src_parts) if src_parts else empty
-    dst_u = np.concatenate(dst_parts) if dst_parts else empty
-    level_u = np.concatenate(lvl_parts) if lvl_parts else empty
-    lv = _bk.build_level_partition(src_u, dst_u, level_u, n_rows)
-    _attach_queue_partition(lv, dst_u, qpred_u, level_u)
-    lv.seg_ptr = np.asarray(seg_ptr, dtype=np.int64)
-    mem_rows = np.flatnonzero(is_mem_rows)
-    return _SuitePlan(n_rows, lv, mem_rows, lv.seg_ptr, blocks,
-                      cls_mem=cls_rows[mem_rows] if classes else None)
-
-
-def _memo_suite_plan(suite: EDagSuite, key, plan: _SuitePlan) -> None:
-    memo = suite._suite_plans
-    memo[key] = plan
-    memo.move_to_end(key)
-    while len(memo) > _SUITE_PLAN_CAP:
-        memo.popitem(last=False)
-
+# ------------------------------------------------------------ union groups
 
 def _member_groups(suite: EDagSuite, n_pairs: int, P: int,
                    pol: ExecPolicy) -> list:
@@ -527,52 +271,37 @@ def _group_grid_batch(suite: EDagSuite, member_idx, out: np.ndarray,
                       pol: ExecPolicy) -> None:
     """Evaluate one replay group's (member, pair, alpha) product into
     ``out`` (global trace indexing): one union plan over the group's
-    blocks, one chunked stacked replay, per-block verification, and the
-    per-member fallback for anything the union schedule fails to
-    certify.  2-D ``alphas`` rows run the class-mode plan — provenance
-    slot chains, class-gathered F fill, and the additional per-block
-    ``_verify_slots`` certificate."""
-    P = len(alphas)
-    classes = alphas.ndim == 2
+    (pair, member) blocks, one chunked stacked replay with per-block
+    verification (``_replay_verify``), and the per-member fallback for
+    anything the union schedule fails to certify.  Block schedules come
+    from each member's reuse tiers (``_schedule``); a fresh recording is
+    kept for the member (``_keep``).  2-D ``alphas`` rows run the
+    class-mode plan — provenance slot chains, class-gathered F fill,
+    and the additional per-block ``_verify_slots`` certificate."""
+    n_classes = alphas.shape[1] if alphas.ndim == 2 else None
+    # empty members own no block: their makespans stay 0
+    where = [(k, pair) for pair in range(len(pairs)) for k in member_idx
+             if suite.members[k].n_vertices]
+    if not where:
+        return
     cls_key = (tuple(suite.members[k].mem_class_digest()
-                     for k in member_idx) if classes else None)
+                     for k in member_idx) if n_classes is not None else None)
     key = (tuple(member_idx), tuple(pairs), float(unit), cls_key)
-    plan = suite._suite_plans.get(key) if pol.use_cache else None
+    plan = _memo_get(suite._suite_plans, key) if pol.use_cache else None
     with span("plan", hit=int(plan is not None)):
-        if plan is not None:
-            suite._suite_plans.move_to_end(key)
-        else:
-            a0 = alphas[0] if classes else float(alphas[0])
-            plan = _build_suite_plan(
-                suite, pairs, unit, a0, pol.use_cache,
-                member_idx=member_idx,
-                n_classes=alphas.shape[1] if classes else None)
+        if plan is None:
+            got = [_schedule(suite.members[k], *pairs[pair], unit, alphas[0],
+                             pol.use_cache, n_classes)
+                   for k, pair in where]
+            plan = _build_plan([sched for sched, _, _ in got], n_classes)
             if pol.use_cache:
-                _memo_suite_plan(suite, key, plan)
-    B = len(plan.blocks)
-    ok = np.zeros((B, P), dtype=bool)
-    chunk = pol.points_chunk(plan.n, P)
-    for c0 in range(0, P, chunk):
-        cols = np.arange(c0, min(c0 + chunk, P))
-        F, R = plan.replay(alphas[cols], unit, pol=pol)
-        with span("reduce"):
-            mk = _bk.segment_max_rows(F[:-1], plan.seg_ptr)
-        for b, blk in enumerate(plan.blocks):
-            if blk is None:           # empty member: makespan 0 everywhere
-                ok[b, cols] = True
-                continue
-            off, n = blk.off, blk.g.n_vertices
-            Fv, Rv = F[off:off + n], R[off:off + n]
-            with span("verify"):
-                okc = _verify_class(blk.g, blk.rank, Fv, Rv,
-                                    blk.O_mem, blk.Om_rel)
-                if blk.prov is not None:
-                    okc &= _verify_slots(blk, Fv)
-                if blk.cs:
-                    okc &= _verify_class(blk.g, blk.rank, Fv, Rv,
-                                         blk.O_alu, blk.Oa_rel)
-            out[blk.trace, cols[okc], blk.pair] = mk[b, okc]
-            ok[b, cols] = okc
+                for blk, (_, _, mk0) in zip(plan.blocks, got):
+                    if mk0 is not None:       # recorded now
+                        _keep(blk, unit, n_classes)
+                _memo_put(suite._suite_plans, key, plan, _SUITE_PLAN_CAP)
+    mk, ok = _replay_verify(plan, alphas, unit, pol)
+    for b, (k, pair) in enumerate(where):
+        out[k, ok[b], pair] = mk[b, ok[b]]
     if not ok.all():
         # any (block, point) the union schedule failed to certify falls
         # back to that member's own batched engine (which re-records and,
@@ -581,12 +310,11 @@ def _group_grid_batch(suite: EDagSuite, member_idx, out: np.ndarray,
         # stale union plan is dropped so repeated suite sweeps converge
         if pol.use_cache:
             suite._suite_plans.pop(key, None)
-        for b, blk in enumerate(plan.blocks):
-            if blk is None:
-                continue
-            bad = np.nonzero(~ok[b])[0]
+        for b, (k, pair) in enumerate(where):
+            bad = np.flatnonzero(~ok[b])
             if len(bad):
-                out[blk.trace, bad, blk.pair] = simulate_batch(
+                blk = plan.blocks[b]
+                out[k, bad, pair] = simulate_batch(
                     blk.g, alphas[bad], m=blk.m, unit=unit,
                     compute_slots=blk.cs, policy=pol)
 

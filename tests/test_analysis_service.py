@@ -25,7 +25,7 @@ import pytest
 from repro.core import EDag, Tracer
 from repro.core.metrics import grid_report
 from repro.core.placement import search_placement
-from repro.core.scheduler import _REPLAY_BYTES_PER_CELL
+from repro.core.plan import REPLAY_BYTES_PER_CELL
 from repro.serve import (AnalysisRequest, AnalysisService, faults,
                          default_deadline_s, default_max_retries)
 
@@ -153,7 +153,7 @@ def test_memory_budget_splits_batches_and_priority_packs_first():
 
     n_pairs = len(GRID["ms"]) * len(GRID["compute_slots"])
     rows2 = 2 * 40 * n_pairs
-    budget = (rows2 * len(ALPHAS) * _REPLAY_BYTES_PER_CELL
+    budget = (rows2 * len(ALPHAS) * REPLAY_BYTES_PER_CELL
               + trace_bytes(1) + trace_bytes(2))
     reqs = [req(0, priority=0), req(1, priority=5), req(2, priority=5)]
     out = svc(mem_budget=budget).process(reqs)

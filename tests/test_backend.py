@@ -127,7 +127,7 @@ def test_jax_kernel_R_out_with_slot_chain_f32():
     """The full simulator-replay shape — qpred slot chains, queue-only
     vertices, zero sentinel row, clamp off — produces identical finish
     AND ready matrices on both backends."""
-    from repro.core.scheduler import _ReplayPlan, _event_loop
+    from repro.core.scheduler import _build_plan, _schedule
 
     rng = np.random.default_rng(31)
     g = EDag()
@@ -137,14 +137,11 @@ def test_jax_kernel_R_out_with_slot_chain_f32():
             if rng.random() < 0.1:
                 g.add_edge(j, i)
     g._finalize()
-    _, topo, O_mem, O_alu = _event_loop(
-        g.is_mem, g._sim_lists(), 2, 80.0, 1.0, 3, record=True)
-    plan = _ReplayPlan(g, topo, O_mem, O_alu, 2, 3)
+    sched, _, _ = _schedule(g, 2, 3, 1.0, 80.0, lookup=False)
+    plan = _build_plan([sched])
     k = 4
-    base = np.empty((g.n_vertices + 1, k), dtype=np.float32)
-    base[:-1] = np.where(plan.is_mem_topo[:, None],
-                         np.linspace(40, 160, k, dtype=np.float32)[None],
-                         np.float32(1.0))
+    base = np.full((g.n_vertices + 1, k), 1.0, dtype=np.float32)
+    base[plan.mem_rows] = np.linspace(40, 160, k, dtype=np.float32)
     base[-1] = 0.0
     R_np = np.zeros_like(base)
     R_jx = np.zeros_like(base)
@@ -164,7 +161,7 @@ def test_jax_kernel_segmented_slot_chains_f32():
     must match the numpy kernel bit-for-bit on finish AND ready times.
     This closes the gap where only single-trace chains were covered."""
     from repro.core import EDagSuite
-    from repro.core.suite import _build_suite_plan
+    from repro.core.scheduler import _build_plan, _schedule
 
     members = []
     for seed, n, p in ((61, 45, 0.10), (62, 25, 0.18), (63, 35, 0.07)):
@@ -178,7 +175,9 @@ def test_jax_kernel_segmented_slot_chains_f32():
         g._finalize()
         members.append(g)
     suite = EDagSuite(members)
-    plan = _build_suite_plan(suite, [(2, 3)], 1.0, 80.0, use_cache=False)
+    scheds = [_schedule(g, 2, 3, 1.0, 80.0, lookup=False)[0]
+              for g in members]
+    plan = _build_plan(scheds)
 
     # the segment invariant itself: every slot chain stays inside its
     # block (or points at the shared sentinel row n_union)
@@ -189,7 +188,7 @@ def test_jax_kernel_segmented_slot_chains_f32():
     real = np.nonzero(qp < n_u)[0]
     assert len(real)                       # the chains are exercised
     assert np.array_equal(tid[real], tid[qp[real]])
-    assert np.array_equal(plan.lv.seg_ptr, suite.offsets)
+    assert np.array_equal(plan.seg_ptr, suite.offsets)
 
     k = 4
     base = np.full((n_u + 1, k), 1.0, dtype=np.float32)
@@ -206,11 +205,8 @@ def test_jax_kernel_segmented_slot_chains_f32():
 
     # and blockwise, the union pass equals each member's own plan run
     # on the same dtype (block-diagonal exactness on the jax path too)
-    from repro.core.scheduler import _ReplayPlan, _event_loop
-    for i, g in enumerate(members):
-        _, topo, O_mem, O_alu = _event_loop(
-            g.is_mem, g._sim_lists(), 2, 80.0, 1.0, 3, record=True)
-        mplan = _ReplayPlan(g, topo, O_mem, O_alu, 2, 3)
+    for i, sched in enumerate(scheds):
+        mplan = _build_plan([sched])
         mb = np.concatenate(
             [base[suite.offsets[i]:suite.offsets[i + 1]], base[-1:]])
         mF = level_accumulate(mplan.lv, mb.copy(), clamp=False,

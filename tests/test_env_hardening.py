@@ -13,7 +13,7 @@ import pytest
 
 from repro.core import (EDag, latency_sweep, select_backend,
                         simulate_reference, schedule_cache as sc)
-from repro.core.scheduler import _REPLAY_MEM_BUDGET, _replay_mem_budget
+from repro.core.plan import REPLAY_MEM_BUDGET, replay_mem_budget
 
 BAD_NUMERIC = ["", "  ", "abc", "-5"]
 
@@ -32,7 +32,7 @@ def _chain(n: int = 12) -> EDag:
 @pytest.mark.parametrize("val", BAD_NUMERIC)
 def test_replay_mem_budget_env_falls_back(monkeypatch, val):
     monkeypatch.setenv("EDAN_REPLAY_MEM_BUDGET", val)
-    assert _replay_mem_budget() == _REPLAY_MEM_BUDGET
+    assert replay_mem_budget() == REPLAY_MEM_BUDGET
     # and a sweep under the bad value completes, bit-identical
     g = _chain()
     alphas = [50.0, 100.0, 200.0]
@@ -42,13 +42,13 @@ def test_replay_mem_budget_env_falls_back(monkeypatch, val):
 
 def test_replay_mem_budget_env_zero_falls_back(monkeypatch):
     monkeypatch.setenv("EDAN_REPLAY_MEM_BUDGET", "0")
-    assert _replay_mem_budget() == _REPLAY_MEM_BUDGET
+    assert replay_mem_budget() == REPLAY_MEM_BUDGET
 
 
 def test_replay_mem_budget_valid_env_and_override(monkeypatch):
     monkeypatch.setenv("EDAN_REPLAY_MEM_BUDGET", "4096")
-    assert _replay_mem_budget() == 4096
-    assert _replay_mem_budget(128) == 128       # explicit arg wins
+    assert replay_mem_budget() == 4096
+    assert replay_mem_budget(128) == 128       # explicit arg wins
 
 
 @pytest.mark.parametrize("val", BAD_NUMERIC)

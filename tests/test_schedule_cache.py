@@ -11,7 +11,19 @@ import pytest
 
 from repro.core import (EDag, latency_sweep, simulate_reference,
                         sweep_grid, schedule_cache as sc)
-from repro.core.scheduler import _plan_from_cache
+from repro.core.scheduler import _build_plan, _schedule
+
+
+def _disk_plan(g, m, cs, topo, O_mem, O_alu, level):
+    """The plan the schedule lookup builds from arrays the disk tier hands
+    it, or None where the lookup refuses them and records afresh (the
+    plan builder recomputes a level assignment that does not fit the
+    augmented edges)."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sc, "load", lambda *key: (topo, O_mem, O_alu, level))
+        mp.setattr(sc, "min_vertices", lambda: 0)
+        sched, _, mk0 = _schedule(g, m, cs, 1.0, 100.0, lookup=True)
+    return _build_plan([sched]) if mk0 is None else None
 
 
 def build_graph(seed: int = 0, n: int = 60, p_edge: float = 0.1,
@@ -294,7 +306,7 @@ def test_plan_from_cache_rejects_malformed_arrays():
     O_alu = np.flatnonzero(~g.is_mem).astype(np.int64)
     level = None
     # sane baseline: identity order is a linear extension (vids are topo)
-    assert _plan_from_cache(g, 4, 2, topo, O_mem, O_alu, level) is not None
+    assert _disk_plan(g, 4, 2, topo, O_mem, O_alu, level) is not None
     bad = [
         (topo[:-1], O_mem, O_alu),                      # wrong length
         (np.zeros(n, dtype=np.int64), O_mem, O_alu),    # not a permutation
@@ -304,16 +316,17 @@ def test_plan_from_cache_rejects_malformed_arrays():
         (topo, O_mem, O_alu[:-1]),                      # wrong O_alu length
     ]
     for t, om, oa in bad:
-        assert _plan_from_cache(g, 4, 2, t, om, oa, None) is None
+        assert _disk_plan(g, 4, 2, t, om, oa, None) is None
     # cs=0 requires an empty ALU order
-    assert _plan_from_cache(g, 4, 0, topo, O_mem, O_alu, None) is None
+    assert _disk_plan(g, 4, 0, topo, O_mem, O_alu, None) is None
     # a garbage persisted level is repaired (levelize fallback), not trusted
     junk_level = np.zeros(n, dtype=np.int64)
-    plan = _plan_from_cache(g, 4, 2, topo, O_mem, O_alu, junk_level)
+    plan = _disk_plan(g, 4, 2, topo, O_mem, O_alu, junk_level)
     assert plan is not None
     if g.n_edges:
-        lv = plan.level_aug
-        assert (lv[plan.rank[g.src]] < lv[plan.rank[g.dst]]).all()
+        blk = plan.blocks[0]
+        lv, rank = blk.level, blk.rank
+        assert (lv[rank[g.src]] < lv[rank[g.dst]]).all()
 
 
 def test_malformed_level_and_shape_entries_degrade_gracefully(cache_env):
@@ -385,12 +398,10 @@ def test_backward_slot_chain_rejected():
     topo = np.arange(3, dtype=np.int64)
     empty = np.zeros(0, dtype=np.int64)
     # O_mem chain 1 -> 0 runs backward in topo rank under m=1
-    assert _plan_from_cache(g, 1, 0, topo,
-                            np.array([1, 0, 2], dtype=np.int64),
-                            empty, None) is None
-    assert _plan_from_cache(g, 1, 0, topo,
-                            np.array([0, 1, 2], dtype=np.int64),
-                            empty, None) is not None
+    assert _disk_plan(g, 1, 0, topo, np.array([1, 0, 2], dtype=np.int64),
+                      empty, None) is None
+    assert _disk_plan(g, 1, 0, topo, np.array([0, 1, 2], dtype=np.int64),
+                      empty, None) is not None
 
 
 def test_foreign_digest_entry_rejected(cache_env):
@@ -435,8 +446,8 @@ def test_reversed_topo_not_linear_extension():
     g._finalize()
     topo = np.array([1, 0], dtype=np.int64)     # violates the edge
     O_mem = np.array([0, 1], dtype=np.int64)
-    assert _plan_from_cache(g, 2, 0, topo, O_mem,
-                            np.zeros(0, dtype=np.int64), None) is None
+    assert _disk_plan(g, 2, 0, topo, O_mem,
+                      np.zeros(0, dtype=np.int64), None) is None
 
 
 # -------------------------------------------------- concurrent store/prune

@@ -259,16 +259,27 @@ def test_suite_reuses_single_trace_schedules_and_vice_versa(cache_env):
     assert sc.stats["record_runs"] == 0 and sc.stats["disk_hits"] == 1
 
 
-def test_suite_warms_member_memo_below_disk_floor(monkeypatch):
+@pytest.mark.parametrize("mode", ["scalar", "classes"])
+def test_suite_warms_member_memo_below_disk_floor(monkeypatch, mode):
     """With persistence disabled (or traces under the disk size floor),
     the member plan memo is the only reuse tier — a suite recording must
     still warm it, so later single-trace sweeps on the same member
-    objects never re-pay the serial recording run."""
+    objects never re-pay the serial recording run.  Class mode has no
+    disk tier at all, so its memo (keyed by the class overlay's digest)
+    is warmed the same way."""
     monkeypatch.setenv("EDAN_SCHEDULE_CACHE", "off")
     sc.reset_stats()
     members = [rand_edag(61, 40), rand_edag(62, 25)]
-    suite = EDagSuite(members)
     alphas = [50.0, 100.0, 200.0]
+    if mode == "classes":
+        for k, g in enumerate(members):
+            rng = np.random.default_rng(300 + k)
+            g.set_mem_classes(rng.integers(0, 2, size=g.n_vertices,
+                                           dtype=np.int32))
+        # rows in one ratio: the recording at the first row certifies
+        # them all, so no point falls back and re-records
+        alphas = np.array([[50.0, 120.0], [100.0, 240.0], [200.0, 480.0]])
+    suite = EDagSuite(members)
     grid = suite_sweep_grid(suite, alphas, ms=[2, 4], compute_slots=[1])
     assert sc.stats["record_runs"] == 2 * 2
     sc.reset_stats()
@@ -392,20 +403,19 @@ def test_suite_class_grid_honors_env_mem_budget(monkeypatch):
     # m), not a per-member loop
     import repro.core.suite as suite_mod
     built = []
-    orig_build = suite_mod._build_suite_plan
+    orig_build = suite_mod._build_plan
 
-    def spy(suite_, pairs, unit, a0, use_cache, member_idx=None,
-            n_classes=None):
-        built.append(n_classes)
-        return orig_build(suite_, pairs, unit, a0, use_cache,
-                          member_idx=member_idx, n_classes=n_classes)
+    def spy(scheds, n_classes=None):
+        built.append((len(scheds), n_classes))
+        return orig_build(scheds, n_classes)
 
-    monkeypatch.setattr(suite_mod, "_build_suite_plan", spy)
+    monkeypatch.setattr(suite_mod, "_build_plan", spy)
     bk.reset_stats()
     full = suite_sweep_grid(suite, rows, ms=ms, compute_slots=css)
     full_chunks = bk.stats["chunks"]
     assert full_chunks > 0
-    assert built and all(nc == 2 for nc in built)
+    # one class-mode plan per distinct m, each over both members' blocks
+    assert built == [(len(members), 2)] * len(ms)
     monkeypatch.setenv("EDAN_REPLAY_MEM_BUDGET", "1")
     bk.reset_stats()
     tiny = suite_sweep_grid(suite, rows, ms=ms, compute_slots=css)
